@@ -63,12 +63,14 @@ def run(report: Report):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src")
     env.pop("XLA_FLAGS", None)
+    # virtual CPU devices by design: the child never reaches for an
+    # accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", BODY], env=env,
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
-        report.add("embedding_strategies.FAILED", 0.0,
-                   proc.stderr.strip().replace("\n", ";")[-200:])
-        return
+        raise RuntimeError("embedding_strategies arm failed:\n"
+                           + proc.stderr.strip()[-2000:])
     for line in proc.stdout.splitlines():
         if line.startswith("ROW,"):
             _, name, us = line.split(",")

@@ -12,12 +12,14 @@ import os
 import sys
 
 from benchmarks.common import Report
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = ("kernel", "train", "hps", "etc", "online", "strategies",
            "roofline")
 
 
 def main() -> None:
+    enable_compile_cache()
     which = [a for a in sys.argv[1:] if not a.startswith("-")] or BENCHES
     report = Report()
     if "kernel" in which:

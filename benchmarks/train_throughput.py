@@ -197,13 +197,16 @@ def _mp_scaling(report: Report, batch: int = 512, steps: int = 8):
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
         env.setdefault("PYTHONPATH", "src")
+        # virtual CPU devices by design: the child never reaches for an
+        # accelerator this process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.run([sys.executable, "-c", code], env=env,
                               capture_output=True, text=True,
                               timeout=600)
         if proc.returncode != 0:
-            report.add(f"dlrm_train.mp_{n_dev}dev", float("nan"),
-                       f"FAILED: {proc.stderr.strip()[-200:]}")
-            continue
+            raise RuntimeError(
+                f"dlrm_train.mp_{n_dev}dev arm failed:\n"
+                f"{proc.stderr.strip()[-2000:]}")
         line = [l for l in proc.stdout.splitlines()
                 if l.startswith("MP_ARM_RESULT ")][-1]
         row = json.loads(line[len("MP_ARM_RESULT "):])

@@ -37,7 +37,7 @@ def main():
         artifact = os.path.join(root, "loadtest.json")
         loadtest_main([
             # 1) demo deploy: 2-model ensemble bundle
-            "--arch", "dlrm-criteo,dcn-criteo",
+            "--arch", "dlrm-criteo,dcn-criteo", "--smoke",
             "--train-steps", "10",
             "--deploy-dir", os.path.join(root, "bundle"),
             # 2) admission: bounded queue, 150ms SLO, deadline batching

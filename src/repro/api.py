@@ -1073,11 +1073,15 @@ class Model:
         dense param tree: live for the former, reloaded from the
         bundle's npz for the latter)."""
         from repro.core.hps.hps import HPS
+        from repro.launch.mesh import make_cache_mesh
         from repro.models.recsys.model import wide_tables
         from repro.serve.server import InferenceServer
+        # striped L1: the stripes spread over as many devices as they tile
+        cache_mesh = make_cache_mesh(hcfg.cache_shards) \
+            if hcfg.cache_shards > 1 else None
         hps = HPS(self.name, self.cfg.tables, pdb, vdb=vdb, bus=bus,
                   cache_capacity=hcfg.cache_capacity,
-                  cache_shards=hcfg.cache_shards,
+                  cache_shards=hcfg.cache_shards, cache_mesh=cache_mesh,
                   payload_dtype=hcfg.payload_dtype)
         wide_hps = None
         if hcfg.wide:
@@ -1088,6 +1092,7 @@ class Model:
                            vdb=vdb, bus=bus,
                            cache_capacity=hcfg.cache_capacity,
                            cache_shards=hcfg.cache_shards,
+                           cache_mesh=cache_mesh,
                            payload_dtype=hcfg.payload_dtype)
         # one HPS per extra N-group collection — its tables are derived
         # from the lowered config, so the ps.json schema is unchanged
@@ -1095,6 +1100,7 @@ class Model:
             g.name: HPS(self.name, g.tables, pdb, vdb=vdb, bus=bus,
                         cache_capacity=hcfg.cache_capacity,
                         cache_shards=hcfg.cache_shards,
+                        cache_mesh=cache_mesh,
                         payload_dtype=hcfg.payload_dtype)
             for g in self.cfg.extra_groups}
         return InferenceServer(self._model, dense, hps,

@@ -1,103 +1,32 @@
-"""JAX version compatibility shims.
+"""The few JAX APIs the repo reaches through one place.
 
-Compat policy (see ROADMAP.md): the repo targets the *installed* JAX first
-and newer APIs opportunistically. Anything that moved between JAX 0.4.x
-and 0.5+/0.6+ goes through this module — call sites never feature-test
-``jax`` themselves:
+The repo targets the installed JAX (0.9) only. Call sites import
+``shard_map``, ``make_mesh`` and ``AxisType`` from here, so a future API
+move is one edit:
 
-* ``shard_map``    — ``jax.shard_map`` (new) vs
-                     ``jax.experimental.shard_map.shard_map`` (0.4.x).
-                     The new ``check_vma`` kwarg maps onto the old
-                     ``check_rep``.
-* ``AxisType``     — ``jax.sharding.AxisType`` is absent before 0.5;
-                     a placeholder enum keeps annotations importable.
-* ``make_mesh``    — the ``axis_types=`` kwarg is absent before 0.5;
-                     dropped when unsupported (all axes default to Auto,
-                     which is what every call site passes anyway).
-* ``shard_map_mesh`` — JAX >= 0.5 wants an ``AbstractMesh`` when a
-                     ``shard_map`` is staged under ``jit`` (a concrete
-                     Mesh bakes device ids into the jaxpr and is
-                     deprecated there); 0.4.x has no AbstractMesh and
-                     takes the concrete Mesh. Call sites that build a
-                     shard_map inside a jitted function route the mesh
-                     through this helper.
+* ``shard_map``  — ``jax.shard_map``. It takes a concrete ``Mesh`` both
+                   eagerly and staged under ``jit``.
+* ``make_mesh``  — ``jax.make_mesh`` with optional ``axis_types``.
+* ``AxisType``   — ``jax.sharding.AxisType``.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Callable, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-__all__ = ["AxisType", "HAS_AXIS_TYPE", "make_mesh", "shard_map",
-           "shard_map_mesh"]
-
-
-# -- AxisType ----------------------------------------------------------------
-
-try:  # JAX >= 0.5
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    HAS_AXIS_TYPE = True
-except ImportError:  # JAX 0.4.x: everything is implicitly Auto
-    class AxisType:  # type: ignore[no-redef]
-        """Placeholder for ``jax.sharding.AxisType`` on old JAX."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-    HAS_AXIS_TYPE = False
+__all__ = ["AxisType", "make_mesh", "shard_map"]
 
 
-# -- make_mesh ---------------------------------------------------------------
-
-if hasattr(jax, "make_mesh"):
-    _MAKE_MESH_AXIS_TYPES = (
-        "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
-    def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-                  axis_types: Optional[Sequence] = None) -> Mesh:
-        if _MAKE_MESH_AXIS_TYPES and axis_types is not None:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 axis_types=tuple(axis_types))
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-else:  # very old JAX: assemble the Mesh by hand
-    from jax.experimental import mesh_utils
-
-    def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-                  axis_types: Optional[Sequence] = None) -> Mesh:
-        devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-        return Mesh(devices, tuple(axis_names))
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              axis_types: Optional[Sequence] = None) -> Mesh:
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=None if axis_types is None
+                         else tuple(axis_types))
 
 
-# -- shard_map ---------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):  # JAX >= 0.6
-    def shard_map(f: Callable, *, mesh: Mesh, in_specs, out_specs,
-                  check_vma: bool = True) -> Callable:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:  # JAX 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f: Callable, *, mesh: Mesh, in_specs, out_specs,
-                  check_vma: bool = True) -> Callable:
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
-
-
-# -- shard_map_mesh ----------------------------------------------------------
-
-def shard_map_mesh(mesh: Mesh):
-    """The mesh object to hand ``shard_map``: on JAX >= 0.6, staging a
-    concrete ``Mesh`` under ``jit`` is deprecated (it bakes device ids
-    into the jaxpr), so return the ``AbstractMesh`` while tracing; on
-    0.4.x (no ``jax.shard_map``, no AbstractMesh support) and for eager
-    calls, the concrete ``Mesh`` is both required and sufficient."""
-    if hasattr(jax, "shard_map"):
-        try:
-            tracing = not jax.core.trace_state_clean()
-        except AttributeError:  # jax.core reshuffles across versions
-            tracing = False
-        if tracing:
-            return getattr(mesh, "abstract_mesh", mesh)
-    return mesh
+def shard_map(f: Callable, *, mesh: Mesh, in_specs, out_specs,
+              check_vma: bool = True) -> Callable:
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

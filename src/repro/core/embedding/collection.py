@@ -162,18 +162,11 @@ class EmbeddingCollection:
                                                 dtype)
         if "loc" in self.groups:
             g = self.groups["loc"]
-            tabs = []
-            tkeys = jax.random.split(keys[2], g.num_tables)
-            for t, k in zip(g.tables, tkeys):
-                scale = 1.0 / np.sqrt(t.vocab_size)
-                tab = jax.random.uniform(k, (t.vocab_size, g.dim), dtype,
-                                         minval=-scale, maxval=scale)
-                pad = self._loc_vmax - t.vocab_size
-                if pad:
-                    tab = jnp.concatenate(
-                        [tab, jnp.zeros((pad, g.dim), dtype)], 0)
-                tabs.append(tab)
-            params["loc"] = jnp.stack(tabs)
+            j = jnp.arange(self._loc_vmax, dtype=jnp.int32)
+            params["loc"] = jnp.stack([
+                init_mega_table(keys[2], g, dtype,
+                                jnp.where(j < t.vocab_size, off + j, -1))
+                for t, off in zip(g.tables, g.offsets)])
         if "hot" in self.groups:
             params["hot"] = init_mega_table(keys[3], self.groups["hot"],
                                             dtype)
@@ -182,14 +175,10 @@ class EmbeddingCollection:
         return params
 
     def _init_sharded(self, key, g: TableGroup, dtype) -> jax.Array:
-        logical = init_mega_table(key, g, dtype)
         rpad = self._padded_rows(g)
-        if rpad > g.total_rows:
-            logical = jnp.concatenate(
-                [logical, jnp.zeros((rpad - g.total_rows, g.dim), dtype)], 0)
-        if self.layout == "striped":
-            logical = logical[self._logical_of_physical(rpad)]
-        return logical
+        rows = self._logical_of_physical(rpad) \
+            if self.layout == "striped" else jnp.arange(rpad)
+        return init_mega_table(key, g, dtype, rows.astype(jnp.int32))
 
     def _logical_of_physical(self, rpad: int) -> jax.Array:
         n = self.n_shards

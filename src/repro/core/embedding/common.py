@@ -55,18 +55,30 @@ def build_group(strategy: str,
 
 
 def init_mega_table(key: jax.Array, group: TableGroup,
-                    dtype=jnp.float32) -> jax.Array:
-    """Uniform(-1/sqrt(V), 1/sqrt(V)) per table, HugeCTR-style init."""
-    parts = []
-    keys = jax.random.split(key, max(1, group.num_tables))
-    bounds = list(group.offsets) + [group.total_rows]
-    for i, (t, k) in enumerate(zip(group.tables, keys)):
-        n = bounds[i + 1] - bounds[i]
-        scale = 1.0 / np.sqrt(max(t.vocab_size, 1))
-        parts.append(jax.random.uniform(k, (n, group.dim), dtype,
-                                        minval=-scale, maxval=scale))
-    return jnp.concatenate(parts, axis=0) if parts else \
-        jnp.zeros((0, group.dim), dtype)
+                    dtype=jnp.float32,
+                    rows: Optional[jax.Array] = None) -> jax.Array:
+    """Uniform(-1/sqrt(V), 1/sqrt(V)) per table, HugeCTR-style init.
+
+    Logical row ``r`` is a pure function of ``(key, r)``, so ``rows``
+    (logical ids in any physical order; ids outside ``[0, total_rows)``
+    become zero padding) yields a striped or padded layout directly, and
+    under ``jit`` with a sharded output each device draws only its own
+    rows — the logical table is never materialized whole.
+    """
+    if rows is None:
+        rows = jnp.arange(group.total_rows, dtype=jnp.int32)
+    if not group.num_tables:
+        return jnp.zeros((rows.shape[0], group.dim), dtype)
+    table = jnp.searchsorted(jnp.asarray(group.offsets[1:], jnp.int32),
+                             rows, side="right")
+    scales = jnp.asarray([1.0 / np.sqrt(max(t.vocab_size, 1))
+                          for t in group.tables], jnp.float32)
+    valid = (rows >= 0) & (rows < group.total_rows)
+    scale = jnp.where(valid, scales[table], 0.0)
+    u = jax.vmap(lambda r: jax.random.uniform(
+        jax.random.fold_in(key, r), (group.dim,), jnp.float32,
+        minval=-1.0, maxval=1.0))(rows)
+    return (u * scale[:, None]).astype(dtype)
 
 
 def global_row_ids(ids: jax.Array, group: TableGroup) -> jax.Array:

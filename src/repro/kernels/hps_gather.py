@@ -13,6 +13,13 @@ the cache's overflow path overwrites separately.
 Grid layout: the payload-tile reduction dim is trailing (Pallas TPU
 requirement for output-block accumulation): grid = (N/bN, C/bC).
 
+The matmul runs at ``Precision.HIGHEST`` (f32 contract precision on the
+MXU): at the default precision Mosaic rounds f32 operands through bf16,
+and a one-hot product must return every payload bit unchanged. f16
+payloads reach the kernel as their raw ``uint16`` bit patterns (a free
+bitcast; Mosaic on v5e cannot load an f16 vector) and are widened to f32
+exactly in VMEM, so the payload is still read at 2 bytes a value.
+
 ``sharded_gather_rows`` is the multi-device entry point for the striped
 L1 payload (companion HPS paper, arXiv 2210.08804 §4): slot ``s`` lives
 on stripe ``s % n_stripes``, stripes are laid out over a 1-D mesh axis,
@@ -34,6 +41,36 @@ from repro import compat
 from repro.kernels.ops import _round_up
 
 
+def _f16_bits_to_f32(bits: jax.Array) -> jax.Array:
+    """Exact f16 -> f32 widening from raw ``uint16`` bit patterns, in
+    integer ops Mosaic lowers (zero, subnormals, inf and nan included)."""
+    h = bits.astype(jnp.int32)
+    sign = (h & 0x8000) << 16
+    exp = (h >> 10) & 0x1F
+    man = h & 0x3FF
+    normal = sign | ((exp + 112) << 23) | (man << 13)
+    special = sign | 0x7F800000 | (man << 13)           # inf / nan
+    val = jax.lax.bitcast_convert_type(
+        jnp.where(exp == 31, special, normal), jnp.float32)
+    tiny = man.astype(jnp.float32) * (2.0 ** -24)       # zero / subnormal
+    tiny = jnp.where(sign != 0, -tiny, tiny)
+    return jnp.where(exp == 0, tiny, val)
+
+
+def _rows_f32(tile: jax.Array) -> jax.Array:
+    """A payload tile widened to f32 in VMEM."""
+    if tile.dtype == jnp.uint16:
+        return _f16_bits_to_f32(tile)
+    return tile.astype(jnp.float32)
+
+
+def _kernel_payload(payload: jax.Array) -> jax.Array:
+    """The payload as the kernel reads it: f16 as its uint16 bits."""
+    if payload.dtype == jnp.float16:
+        return jax.lax.bitcast_convert_type(payload, jnp.uint16)
+    return payload
+
+
 def _gather_kernel(slots_ref, payload_ref, o_ref, *, bc: int):
     c = pl.program_id(1)
 
@@ -47,7 +84,8 @@ def _gather_kernel(slots_ref, payload_ref, o_ref, *, bc: int):
     iota = jax.lax.broadcasted_iota(jnp.int32, (bn, bc), 1)
     onehot = ((rel[:, None] == iota) & (slots >= 0)[:, None])
     o_ref[...] += jnp.dot(onehot.astype(jnp.float32),
-                          payload_ref[...].astype(jnp.float32),
+                          _rows_f32(payload_ref[...]),
+                          precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
 
 
@@ -59,6 +97,7 @@ def gather_rows(payload: jax.Array, slots: jax.Array, *,
     c, d = payload.shape
     n = slots.shape[0]
     grid = (n // block_n, c // block_c)
+    payload = _kernel_payload(payload)
     return pl.pallas_call(
         functools.partial(_gather_kernel, bc=block_c),
         grid=grid,
@@ -90,8 +129,8 @@ def _dq_gather_kernel(slots_ref, payload_ref, scales_ref, o_ref, *, bc: int):
     onehot = ((rel[:, None] == iota) & (slots >= 0)[:, None])
     scales = scales_ref[...][:, 0]                    # [bC] f32
     scaled = onehot.astype(jnp.float32) * scales[None, :]
-    o_ref[...] += jnp.dot(scaled,
-                          payload_ref[...].astype(jnp.float32),
+    o_ref[...] += jnp.dot(scaled, _rows_f32(payload_ref[...]),
+                          precision=jax.lax.Precision.HIGHEST,
                           preferred_element_type=jnp.float32)
 
 
@@ -108,6 +147,7 @@ def dequant_gather_rows(payload: jax.Array, scales: jax.Array,
     c, d = payload.shape
     n = slots.shape[0]
     grid = (n // block_n, c // block_c)
+    payload = _kernel_payload(payload)
     return pl.pallas_call(
         functools.partial(_dq_gather_kernel, bc=block_c),
         grid=grid,
@@ -182,7 +222,7 @@ def sharded_gather_rows(stripes: jax.Array, slots: jax.Array, *,
         use_kernel=use_kernel, block_n=block_n, block_c=block_c,
         interpret=interpret)
     spec = P(axis) if size > 1 else P()
-    fn = compat.shard_map(body, mesh=compat.shard_map_mesh(mesh),
+    fn = compat.shard_map(body, mesh=mesh,
                           in_specs=(spec, P()), out_specs=P(),
                           check_vma=False)
     return fn(stripes, slots.astype(jnp.int32))
@@ -250,7 +290,7 @@ def sharded_dequant_gather_rows(stripes: jax.Array, scales: jax.Array,
         use_kernel=use_kernel, block_n=block_n, block_c=block_c,
         interpret=interpret)
     spec = P(axis) if size > 1 else P()
-    fn = compat.shard_map(body, mesh=compat.shard_map_mesh(mesh),
+    fn = compat.shard_map(body, mesh=mesh,
                           in_specs=(spec, spec, P()), out_specs=P(),
                           check_vma=False)
     return fn(stripes, scales, slots.astype(jnp.int32))

@@ -145,20 +145,20 @@ def pooled_cache_lookup(payload: jax.Array, slots: jax.Array,
     """Serving-path pooled gather: ``payload [C, D]``, ``slots [B, H]``
     (-1 = hole) -> sum-pooled ``[B, D]``.
 
-    Inference-only (no vjp): the MXU one-hot-matmul kernel on TPU, the
-    equivalent XLA take+sum elsewhere — same switch as ``cache_gather``.
-    With per-row ``scales`` (int8 payloads) the gather is the fused
-    dequantize kernel and the pooling sum stays inside the same jit.
+    Inference-only (no vjp): the ``hps_gather`` one-hot-matmul kernel on
+    TPU, the equivalent XLA take elsewhere — same switch as
+    ``cache_gather`` — with the pooling sum inside the same jit. With
+    per-row ``scales`` (int8 payloads) the gather is the fused dequantize
+    kernel.
     """
+    b, h = slots.shape
+    flat = slots.reshape(-1)
     if scales is not None:
-        b, h = slots.shape
-        rows = _dequant_cache_gather_jit(payload, scales, slots.reshape(-1),
-                                         256, 512, not _interpret())
-        return rows.reshape(b, h, -1).sum(axis=1)
-    if _interpret():
-        from repro.kernels import ref as _ref
-        return _ref.embedding_lookup_ref(payload, slots)
-    return fused_embedding_lookup(payload, slots)
+        rows = _dequant_cache_gather_jit(payload, scales, flat, 256, 512,
+                                         not _interpret())
+    else:
+        rows = _cache_gather_jit(payload, flat, 256, 512, not _interpret())
+    return rows.reshape(b, h, -1).sum(axis=1)
 
 
 def cache_gather(payload: jax.Array, slots, *, scales=None,
@@ -263,15 +263,9 @@ def sharded_pooled_lookup(stripes: jax.Array, slots: jax.Array, *,
                                            use_kernel=not _interpret(),
                                            interpret=_interpret())
         return rows.reshape(b, h, -1).sum(axis=1)
-    flat = stripes.reshape(-1, stripes.shape[-1])
-    if scales is not None:
-        b, h = slots.shape
-        rows = _dequant_cache_gather_jit(
-            flat, scales.reshape(-1),
-            flatten_striped_slots(stripes, slots).reshape(-1),
-            256, 512, not _interpret())
-        return rows.reshape(b, h, -1).sum(axis=1)
-    return pooled_cache_lookup(flat, flatten_striped_slots(stripes, slots))
+    return pooled_cache_lookup(stripes.reshape(-1, stripes.shape[-1]),
+                               flatten_striped_slots(stripes, slots),
+                               None if scales is None else scales.reshape(-1))
 
 
 # ---------------------------------------------------------------------------

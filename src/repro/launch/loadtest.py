@@ -22,7 +22,7 @@ sides (driver-observed and server counters) — persists to
 
   # demo: train 2 recipes briefly, deploy an ensemble bundle, load-test it
   PYTHONPATH=src python -m repro.launch.loadtest \
-      --arch dlrm-criteo,dcn-criteo --qps 30 --duration 3 \
+      --arch dlrm-criteo,dcn-criteo --smoke --qps 30 --duration 3 \
       --slo-ms 100 --queue-depth 64 --overload-qps 400
 
   # load-test an existing bundle; record the workload for exact replay
@@ -45,6 +45,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from repro.configs.registry import RECSYS_RECIPES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import _train_and_deploy, build_server_from_config
 from repro.loadgen.driver import OpenLoopDriver
 from repro.loadgen.workload import (ModelShape, Workload, WorkloadConfig,
@@ -202,6 +203,9 @@ def main(argv=None):
                          "recipes first (comma-separated; 2+ archs "
                          "deploy an ensemble bundle)")
     ap.add_argument("--train-steps", type=int, default=20)
+    ap.add_argument("--smoke", action="store_true",
+                    help="demo mode: train the reduced config "
+                         "(CPU-runnable) instead of the published widths")
     ap.add_argument("--deploy-dir", default=None)
     ap.add_argument("--cache-capacity", type=int, default=None)
     # workload
@@ -252,6 +256,7 @@ def main(argv=None):
                          "steady phase shed nothing and the overload "
                          "phase (if run) shed something")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ps_path = args.config
     if ps_path is None:
@@ -263,7 +268,7 @@ def main(argv=None):
         deploy_dir = args.deploy_dir or tempfile.mkdtemp(prefix="hps_")
         ps_path = _train_and_deploy(archs, args.train_steps,
                                     max(args.rows, 16), deploy_dir,
-                                    args.cache_capacity)
+                                    args.cache_capacity, smoke=args.smoke)
         print(f"deployment bundle: {deploy_dir}")
 
     built, servers, models, shapes, submit = _stand_up(

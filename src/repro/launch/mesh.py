@@ -38,17 +38,22 @@ def make_test_mesh(shape: Sequence[int] = (1, 1),
 
 
 def make_cache_mesh(stripes: int, *, axis: str = "cache") -> Mesh:
-    """1-D mesh for the striped HPS L1 payload: as many devices as can
-    tile ``stripes`` evenly (so stripe ``i`` lands on device
-    ``i * size / stripes``), degrading to a 1-device mesh when the
-    stripe count and the device count don't divide."""
+    """1-D mesh for the striped HPS L1 payload over ``min(stripes,
+    devices)`` devices, stripe ``i`` on device ``i * size / stripes``.
+
+    Raises when the stripes cannot tile that many devices evenly: a
+    smaller mesh would quietly hold the payload on fewer chips than the
+    deployment asked for."""
     import numpy as np
 
-    n_dev = len(jax.devices())
-    size = min(stripes, n_dev)
-    while size > 1 and stripes % size:
-        size -= 1
-    return Mesh(np.asarray(jax.devices()[:size]), (axis,))
+    devices = jax.devices()
+    size = min(stripes, len(devices))
+    if stripes < 1 or stripes % size:
+        raise ValueError(
+            f"{stripes} cache stripes cannot tile {size} of the "
+            f"{len(devices)} devices evenly; use a stripe count that is "
+            f"a multiple of {len(devices)} or at most it")
+    return Mesh(np.asarray(devices[:size]), (axis,))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -43,6 +43,7 @@ from repro.api import (CreateSolver, DataReaderParams, DenseLayer, Input,
 from repro.configs.base import ETCParams
 from repro.core.hps.message_bus import MessageBus
 from repro.core.hps.volatile_db import VolatileDB
+from repro.launch.compile_cache import enable_compile_cache
 from repro.online import (OnlineTrainer, UpdatePublisher,
                           probe_prediction, wait_visible)
 
@@ -213,6 +214,7 @@ def main(argv=None) -> None:
                     help="fail unless the serving window holds the "
                     "hot-path invariants with the consumer loop active")
     a = ap.parse_args(argv)
+    enable_compile_cache()
     run_online(base_steps=a.base_steps, online_steps=a.online_steps,
                passes=a.passes, cache_rows=a.cache_rows,
                requests=a.requests, batch=a.batch, ps=a.ps,

@@ -19,13 +19,14 @@ ONE shared message bus — bit-exact with per-model in-process servers.
       --requests 50 --batch 64
 
   # demo: train a recipe for a few steps, deploy, then serve THROUGH
-  # the written bundle (wdl exercises the two-HPS wide path)
+  # the written bundle (wdl exercises the two-HPS wide path; --smoke
+  # trains the reduced CPU-sized config, without it the full widths)
   PYTHONPATH=src python -m repro.launch.serve --arch dlrm-criteo \
-      --requests 50 --batch 64
+      --smoke --requests 50 --batch 64
 
   # demo: 2-model ensemble bundle, one storage backend, per-model stats
   PYTHONPATH=src python -m repro.launch.serve \
-      --arch dlrm-criteo,dcn-criteo --requests 10 --batch 32
+      --arch dlrm-criteo,dcn-criteo --smoke --requests 10 --batch 32
 """
 from __future__ import annotations
 
@@ -43,6 +44,7 @@ from repro.configs.base import (
     EnsembleConfig, HPSConfig, ps_config_from_dict, recsys_config_hash,
 )
 from repro.configs.registry import RECSYS_RECIPES
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def load_ps_config(path: str):
@@ -163,12 +165,14 @@ def build_server_from_config(ps_path: str, *, mesh=None, vdb=None,
         models
 
 
-def _train_model(arch: str, train_steps: int, batch: int):
+def _train_model(arch: str, train_steps: int, batch: int, *,
+                 smoke: bool):
     """Train one recipe briefly via the graph API (novel graph archs
-    included — they compile through the generic dense-graph program)."""
+    included — they compile through the generic dense-graph program);
+    ``smoke`` picks the reduced config over the published widths."""
     from repro.api import Solver
     mod = importlib.import_module(RECSYS_RECIPES[arch])
-    m = mod.build_model(smoke=True,
+    m = mod.build_model(smoke=smoke,
                         solver=Solver(batch_size=batch, lr=1e-2))
     m.compile()
     hist = m.fit(steps=train_steps)
@@ -180,13 +184,15 @@ def _train_model(arch: str, train_steps: int, batch: int):
 def _train_and_deploy(archs, train_steps: int, batch: int,
                       deploy_dir: str,
                       cache_capacity: Optional[int],
-                      payload_dtype: str = "f32") -> str:
+                      payload_dtype: str = "f32", *,
+                      smoke: bool) -> str:
     """Demo path: train the recipes briefly, write ONE deployment
     bundle (single-model or ensemble), return the ps.json path.
     ``cache_capacity=None`` lets ensembles size per-model L1 caches
     from table hotness; ``payload_dtype`` persists in the bundle's
     ps.json, so the rebuilt server serves the same precision mode."""
-    models = [_train_model(a, train_steps, batch) for a in archs]
+    models = [_train_model(a, train_steps, batch, smoke=smoke)
+              for a in archs]
     if len(models) == 1:
         models[0].deploy(deploy_dir,
                          cache_capacity=cache_capacity or 2048,
@@ -359,6 +365,9 @@ def main():
                          "are novel graphs served via the generic "
                          "compiler)")
     ap.add_argument("--train-steps", type=int, default=20)
+    ap.add_argument("--smoke", action="store_true",
+                    help="demo mode: train the reduced config "
+                         "(CPU-runnable) instead of the published widths")
     ap.add_argument("--requests", type=int, default=50)
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--cache-capacity", type=int, default=None,
@@ -378,6 +387,7 @@ def main():
                          "exactly one device->host sync and zero "
                          "post-warmup recompiles")
     args = ap.parse_args()
+    enable_compile_cache()
 
     ps_path = args.config
     if ps_path is None:
@@ -390,7 +400,7 @@ def main():
         ps_path = _train_and_deploy(archs, args.train_steps, args.batch,
                                     deploy_dir, args.cache_capacity,
                                     payload_dtype=args.payload_dtype
-                                    or "f32")
+                                    or "f32", smoke=args.smoke)
         print(f"deployment bundle: {deploy_dir}")
         payload_override = None          # the bundle already carries it
     else:

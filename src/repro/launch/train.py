@@ -3,8 +3,9 @@
 Builds the mesh from flags (or the production config), constructs the
 model for ``--arch``, and drives the fault-tolerant Trainer with async
 checkpoints. On a real TPU pod each host runs this same script under
-``jax.distributed``; on CPU it runs the reduced smoke config so the full
-path is exercisable anywhere.
+``jax.distributed``. ``--smoke`` selects the reduced config (CPU-runnable);
+without it the recipe trains at its published widths, on any device
+count.
 
   PYTHONPATH=src python -m repro.launch.train --arch dlrm-criteo \
       --steps 200 --batch 1024 --ckpt-dir /tmp/ckpt
@@ -21,6 +22,7 @@ import numpy as np
 from repro.configs.registry import (
     LM_ARCHS, RECSYS_RECIPES, reduce_for_smoke,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_test_mesh
 
 
@@ -49,6 +51,7 @@ def main():
                     help="'auto' | 'single' | 'multi' | 'RxC'")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     if args.mesh == "auto":
@@ -75,7 +78,7 @@ def main():
                         grad_allreduce_dtype=args.grad_ar_dtype,
                         mode=args.mode, comm=args.comm,
                         ckpt_interval=args.ckpt_interval)
-        model = recipe.build_model(smoke=args.smoke or n_dev == 1,
+        model = recipe.build_model(smoke=args.smoke,
                                    solver=solver, mesh=mesh)
         model.compile()
         model.summary()
@@ -91,7 +94,7 @@ def main():
     from repro.models.lm.backbone import LMModel
 
     cfg = LM_ARCHS[args.arch]
-    if args.smoke or n_dev == 1:
+    if args.smoke:
         cfg = reduce_for_smoke(cfg)
     with mesh:
         model = LMModel(cfg, mesh,

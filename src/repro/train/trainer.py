@@ -72,7 +72,11 @@ class Trainer:
         return jax.device_put(params, self._shardings)
 
     def init_state(self, seed: int = 0):
-        params = self._place(self.model.init(jax.random.PRNGKey(seed)))
+        # one compiled program (eager init dispatches, and on a TPU
+        # compiles, op by op); sharded, each device draws only its own
+        # rows of every table
+        params = jax.jit(self.model.init, out_shardings=self._shardings)(
+            jax.random.PRNGKey(seed))
         opt_state = init_opt_state(params, self.tcfg)
         return params, opt_state
 

@@ -149,6 +149,16 @@ def test_sharded_store_validation():
         ShardedPayloadStore(16, 8, shards=0)
 
 
+@pytest.mark.parametrize("stripes", [0, 6, 10])
+def test_make_cache_mesh_refuses_untileable_stripes(monkeypatch, stripes):
+    """More stripes than devices must tile them evenly: the mesh is
+    never quietly shrunk onto fewer devices than the stripes span."""
+    from repro.launch import mesh as mesh_mod
+    monkeypatch.setattr(mesh_mod.jax, "devices", lambda: [object()] * 4)
+    with pytest.raises(ValueError, match="cannot tile"):
+        mesh_mod.make_cache_mesh(stripes)
+
+
 def test_sharded_gather_over_real_devices():
     """The shard_map path: stripes distributed over 4 virtual CPU
     devices, per-device gather + one psum, vs the oracle (subprocess so
@@ -165,6 +175,13 @@ slots = rng.integers(-1, 128, size=37)
 want = np.asarray(ref.sharded_gather_ref(stripes, jnp.asarray(slots)))
 mesh = make_cache_mesh(8)
 assert mesh.shape["cache"] == 4
+assert make_cache_mesh(3).shape["cache"] == 3
+try:                      # 6 stripes cannot tile 4 devices evenly
+    make_cache_mesh(6)
+except ValueError:
+    pass
+else:
+    raise AssertionError("make_cache_mesh(6) over 4 devices must raise")
 for kw in ({}, {"use_kernel": True}):
     got = np.asarray(ops.sharded_cache_gather(stripes, slots, mesh=mesh,
                                               **kw))

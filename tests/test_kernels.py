@@ -9,6 +9,7 @@ from repro.kernels import ops
 from repro.kernels import ref
 from repro.kernels import embedding_lookup as el
 from repro.kernels import dot_interaction as di
+from repro.kernels import hps_gather as hg
 
 
 # ---------------------------------------------------------------------------
@@ -95,6 +96,59 @@ def test_lookup_block_shape_sweep(block_b, block_v):
     expected = ref.embedding_lookup_ref(table, rows)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# hps_gather: f16 payloads
+# ---------------------------------------------------------------------------
+
+def test_f16_bits_widen_exactly():
+    """Every uint16 pattern widens to the f32 numpy gives for that f16
+    (zeros, subnormals, normals, inf; nan stays nan with its sign)."""
+    bits = np.arange(65536, dtype=np.uint16)
+    want = bits.view(np.float16).astype(np.float32)
+    got = np.asarray(hg._f16_bits_to_f32(jnp.asarray(bits)))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(np.signbit(got[nan]), np.signbit(want[nan]))
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+def _f16_payload(c, d):
+    """Finite f16 rows spanning normals, subnormals and signed zeros."""
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((c, d)).astype(np.float16)
+    x[::7] *= np.float16(2.0 ** -16)             # subnormal rows
+    x[3] = np.float16(-0.0)
+    x[5] = np.float16(65504.0)                  # largest finite
+    return jnp.asarray(x)
+
+
+def _slots(n, c):
+    s = np.random.default_rng(1).integers(-1, c, size=(n, 1))
+    s[:4, 0] = [0, 3, 5, 7]
+    return jnp.asarray(s, jnp.int32)
+
+
+def test_gather_rows_f16_interpret():
+    c, d, n = 1024, 16, 64
+    payload, slots = _f16_payload(c, d), _slots(n, c)
+    got = hg.gather_rows(payload, slots, block_n=32, block_c=256,
+                         interpret=True)
+    want = ref.cache_gather_ref(payload, slots[:, 0])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_dequant_gather_rows_f16_interpret():
+    c, d, n = 1024, 16, 64
+    payload, slots = _f16_payload(c, d), _slots(n, c)
+    scales = jax.random.uniform(jax.random.PRNGKey(2), (c,), jnp.float32,
+                                0.5, 2.0)
+    got = hg.dequant_gather_rows(payload, scales[:, None], slots,
+                                 block_n=32, block_c=256, interpret=True)
+    want = ref.dequant_gather_ref(payload, scales, slots[:, 0])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
