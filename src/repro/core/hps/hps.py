@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import EmbeddingTableConfig
 from repro.core.hps.embedding_cache import DeviceEmbeddingCache, LookupPlan
 from repro.core.hps.message_bus import Consumer, MessageBus
@@ -144,19 +145,21 @@ class HPS:
         dim = self._table_cfg[table].dim
 
         def fetch(ids: np.ndarray) -> np.ndarray:
-            mask, rows = self.vdb.query(self._vdb_key(table), ids)
-            if rows is None:
-                rows = np.zeros((len(ids), dim), np.float32)
-            if not mask.all():
-                missing = ids[~mask]
-                fetched = self.pdb.fetch(self.model_name, table, missing)
-                with self._l3_stats_lock:
-                    self._l3_fetch_calls[table] += 1
-                    self._l3_fetch_rows[table] += len(missing)
-                rows[~mask] = fetched
-                self.vdb.insert(self._vdb_key(table), missing,
-                                fetched)  # promote
-            return rows
+            with tracing.span("hps.miss_fetch", table=table):
+                mask, rows = self.vdb.query(self._vdb_key(table), ids)
+                if rows is None:
+                    rows = np.zeros((len(ids), dim), np.float32)
+                if not mask.all():
+                    missing = ids[~mask]
+                    fetched = self.pdb.fetch(self.model_name, table,
+                                             missing)
+                    with self._l3_stats_lock:
+                        self._l3_fetch_calls[table] += 1
+                        self._l3_fetch_rows[table] += len(missing)
+                    rows[~mask] = fetched
+                    self.vdb.insert(self._vdb_key(table), missing,
+                                    fetched)  # promote
+                return rows
         return fetch
 
     def _dim(self, table: str) -> int:
@@ -233,8 +236,10 @@ class HPS:
 
     def _probe(self, ti: int, blocks: List[np.ndarray]) -> LookupPlan:
         """HOST stage for table ``ti``: probe + coalesced miss fetch."""
-        flat = np.ascontiguousarray(blocks[ti], np.int64).reshape(-1)
-        return self.caches[self.tables[ti].name].probe(flat)
+        name = self.tables[ti].name
+        with tracing.span("hps.probe", table=name):
+            flat = np.ascontiguousarray(blocks[ti], np.int64).reshape(-1)
+            return self.caches[name].probe(flat)
 
     def _device_stage(self, ti: int, plan: LookupPlan, b: int, bp: int,
                       h: int) -> Tuple[jax.Array, jax.Array]:
@@ -253,8 +258,9 @@ class HPS:
                                            int]]) -> jax.Array:
         """Run table ``ti``'s device stage and record its outputs — the
         per-plan bookkeeping shared by every engine variant."""
-        sb, payload = self._device_stage(ti, plan, b, bp,
-                                         blocks[ti].shape[1])
+        with tracing.span("hps.device_stage", table=self.tables[ti].name):
+            sb, payload = self._device_stage(ti, plan, b, bp,
+                                             blocks[ti].shape[1])
         slot_blocks.append(sb)
         payloads.append(payload)
         if len(plan.ov_idx):
@@ -275,30 +281,33 @@ class HPS:
                   overflow: List[Tuple[int, np.ndarray, np.ndarray, int]],
                   b: int) -> jax.Array:
         """The single jitted pooled-stack dispatch (+ rare overflow fix)."""
-        combiners = tuple("mean" if t.combiner == "mean" else "sum"
-                          for t in self.tables)
-        stack = functools.partial(
-            _pooled_stack, tuple(payloads), tuple(slot_blocks), combiners,
-            shards=self.cache_shards, mesh=self.cache_mesh)
-        if not overflow:
-            return stack()[:b]
+        with tracing.span("hps.pooled_stack", rows=b):
+            combiners = tuple("mean" if t.combiner == "mean" else "sum"
+                              for t in self.tables)
+            stack = functools.partial(
+                _pooled_stack, tuple(payloads), tuple(slot_blocks),
+                combiners, shards=self.cache_shards, mesh=self.cache_mesh)
+            if not overflow:
+                return stack()[:b]
 
-        # rare path: some ids exceeded L1 evictable capacity; add their
-        # contribution host-side, then apply the mean denominators exactly
-        out = stack(apply_mean=False)[:b]
-        dim = self.tables[0].dim
-        corr = np.zeros((b, len(self.tables), dim), np.float32)
-        for ti, ov_idx, ov_rows, h in overflow:
-            np.add.at(corr[:, ti, :], ov_idx // h, ov_rows)
-        out = out + jnp.asarray(corr)
-        mean_mask = np.asarray([c == "mean" for c in combiners])
-        if mean_mask.any():
-            denom = np.stack(
-                [np.maximum((blk >= 0).sum(axis=1), 1) for blk in blocks],
-                axis=1).astype(np.float32)[:, :, None]
-            out = jnp.where(jnp.asarray(mean_mask)[None, :, None],
-                            out / jnp.asarray(denom), out)
-        return out
+            # rare path: some ids exceeded L1 evictable capacity; add
+            # their contribution host-side, then apply the mean
+            # denominators exactly
+            out = stack(apply_mean=False)[:b]
+            dim = self.tables[0].dim
+            corr = np.zeros((b, len(self.tables), dim), np.float32)
+            for ti, ov_idx, ov_rows, h in overflow:
+                np.add.at(corr[:, ti, :], ov_idx // h, ov_rows)
+            out = out + jnp.asarray(corr)
+            mean_mask = np.asarray([c == "mean" for c in combiners])
+            if mean_mask.any():
+                denom = np.stack(
+                    [np.maximum((blk >= 0).sum(axis=1), 1)
+                     for blk in blocks],
+                    axis=1).astype(np.float32)[:, :, None]
+                out = jnp.where(jnp.asarray(mean_mask)[None, :, None],
+                                out / jnp.asarray(denom), out)
+            return out
 
     def lookup(self, cat: np.ndarray, hotness: Optional[List[int]] = None,
                *, pipelined: bool = False) -> jax.Array:

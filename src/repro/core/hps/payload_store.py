@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.kernels import ops
 from repro.kernels.ops import _round_up
 
@@ -145,28 +146,31 @@ class ShardedPayloadStore:
         (padding repeats the first slot — idempotent under ``set``).
         In compressed modes the f32 rows quantize host-side first; int8
         additionally rebinds the scale vector at the same slots."""
-        rows, scales = quantize_rows(np.asarray(rows), self.payload_dtype)
-        pad = _round_up(len(slots), 64) - len(slots)
-        if pad:
-            slots = np.concatenate([slots, np.full(pad, slots[0])])
-            rows = np.concatenate(
-                [rows, np.broadcast_to(rows[:1], (pad, rows.shape[1]))])
-            if scales is not None:
-                scales = np.concatenate(
-                    [scales, np.broadcast_to(scales[:1], (pad,))])
-        if self.shards == 1:
-            idx = jnp.asarray(slots, jnp.int32)
-            self._payload = self._payload.at[idx].set(jnp.asarray(rows))
-            if scales is not None:
-                self._scales = self._scales.at[idx].set(jnp.asarray(scales))
-        else:
-            stripe = jnp.asarray(slots % self.shards, jnp.int32)
-            local = jnp.asarray(slots // self.shards, jnp.int32)
-            self._payload = self._payload.at[stripe, local].set(
-                jnp.asarray(rows))
-            if scales is not None:
-                self._scales = self._scales.at[stripe, local].set(
-                    jnp.asarray(scales))
+        with tracing.span("hps.l1_scatter", rows=len(slots)):
+            rows, scales = quantize_rows(np.asarray(rows),
+                                         self.payload_dtype)
+            pad = _round_up(len(slots), 64) - len(slots)
+            if pad:
+                slots = np.concatenate([slots, np.full(pad, slots[0])])
+                rows = np.concatenate(
+                    [rows, np.broadcast_to(rows[:1], (pad, rows.shape[1]))])
+                if scales is not None:
+                    scales = np.concatenate(
+                        [scales, np.broadcast_to(scales[:1], (pad,))])
+            if self.shards == 1:
+                idx = jnp.asarray(slots, jnp.int32)
+                self._payload = self._payload.at[idx].set(jnp.asarray(rows))
+                if scales is not None:
+                    self._scales = self._scales.at[idx].set(
+                        jnp.asarray(scales))
+            else:
+                stripe = jnp.asarray(slots % self.shards, jnp.int32)
+                local = jnp.asarray(slots // self.shards, jnp.int32)
+                self._payload = self._payload.at[stripe, local].set(
+                    jnp.asarray(rows))
+                if scales is not None:
+                    self._scales = self._scales.at[stripe, local].set(
+                        jnp.asarray(scales))
 
     # -- read ----------------------------------------------------------------
 

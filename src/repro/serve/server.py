@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import EmbeddingTableConfig, RecsysConfig
 from repro.core.hps.hps import HPS
 from repro.core.hps.message_bus import MessageBus
@@ -159,6 +160,8 @@ class InferenceServer:
         "requests_delivered": "_stats_lock",
         "requests_expired": "_stats_lock",
         "slo_violations": "_stats_lock",
+        "queue_wait_s": "_stats_lock",
+        "requests_drained": "_stats_lock",
         "_service_ms_per_row": "_stats_lock",
         "_closed": "_admit_lock",
         "requests_shed": "_admit_lock",
@@ -214,6 +217,13 @@ class InferenceServer:
         self.requests_delivered = 0
         self.requests_expired = 0
         self.slo_violations = 0
+        #: seconds drained requests waited in the queue, summed, and how
+        #: many requests that sum covers (``repro.tracing`` lists both)
+        self.queue_wait_s = 0.0
+        self.requests_drained = 0
+        #: sequence number of the latest request group coalesced; only
+        #: the serve-loop thread touches it (the id of the spans' groups)
+        self._group = -1
         #: EWMA of observed service time per delivered row, feeding the
         #: deadline batcher's cut decision (None until the first group)
         self._service_ms_per_row: Optional[float] = None
@@ -274,6 +284,9 @@ class InferenceServer:
                 self.requests_shed += shed
 
     def _record_latency(self, t0: float, rows: int = 0) -> None:
+        """Record one group's service time, from ``t0`` (its drain from
+        the queue, or the start of a direct ``predict``) to now. The time
+        its requests waited in the queue before that is ``queue_wait_s``."""
         ms = (time.perf_counter() - t0) * 1e3
         with self._stats_lock:
             self.latency_hist.record(ms)
@@ -301,23 +314,24 @@ class InferenceServer:
 
     def _dense_forward(self, dense: np.ndarray, emb: jax.Array,
                        wide: Optional[jax.Array],
-                       extras: Optional[Dict[str, jax.Array]] = None
-                       ) -> jax.Array:
+                       extras: Optional[Dict[str, jax.Array]] = None,
+                       group: int = -1) -> jax.Array:
         """The one jitted dense-net dispatch + host-side sigmoid — shared
         by every engine so outputs are bit-identical across them."""
-        d = jnp.asarray(dense)
-        if self.extra_hps:
-            if wide is not None:
-                out = self._predict(self.dense_params, d, emb, wide,
-                                    extras or {})
+        with tracing.span("server.dense_forward", group=group):
+            d = jnp.asarray(dense)
+            if self.extra_hps:
+                if wide is not None:
+                    out = self._predict(self.dense_params, d, emb, wide,
+                                        extras or {})
+                else:
+                    out = self._predict_nowide(self.dense_params, d, emb,
+                                               extras or {})
+            elif wide is not None:
+                out = self._predict(self.dense_params, d, emb, wide)
             else:
-                out = self._predict_nowide(self.dense_params, d, emb,
-                                           extras or {})
-        elif wide is not None:
-            out = self._predict(self.dense_params, d, emb, wide)
-        else:
-            out = self._predict_nowide(self.dense_params, d, emb)
-        return jax.nn.sigmoid(out)
+                out = self._predict_nowide(self.dense_params, d, emb)
+            return jax.nn.sigmoid(out)
 
     def predict(self, dense: np.ndarray, cat: np.ndarray) -> np.ndarray:
         t0 = time.perf_counter()
@@ -384,29 +398,30 @@ class InferenceServer:
         lookup plans carry their own lock-consistent payload snapshots,
         so a refresh scatter landing between a query's probe and its
         device stage can never tear that query's view."""
-        sweep = False
-        if self.refresh_poll_s is not None:
-            now = time.monotonic()
-            if now - self._last_poll >= self.refresh_poll_s:
-                self._last_poll = now
-                sweep = True
-        applied = refreshed = 0            # the bus/refresh IO runs
-        for hps in (self.hps, self.wide_hps,    # unlocked; counters
-                    *self.extra_hps.values()):  # update in one step below
-            if hps is None:
-                continue
-            if hps.consumer is not None:
-                applied += hps.apply_updates()
-            if sweep:
-                hps.schedule_refresh()
-            if hps.refresh_backlog():
-                refreshed += hps.refresh_step(self.refresh_budget)
-        if applied or refreshed:
-            with self._stats_lock:
-                self.updates_applied += applied
-                self.rows_refreshed += refreshed
-        if self.on_tick is not None:
-            self.on_tick()
+        with tracing.span("server.refresh_tick", group=self._group):
+            sweep = False
+            if self.refresh_poll_s is not None:
+                now = time.monotonic()
+                if now - self._last_poll >= self.refresh_poll_s:
+                    self._last_poll = now
+                    sweep = True
+            applied = refreshed = 0        # the bus/refresh IO runs
+            for hps in (self.hps, self.wide_hps,    # unlocked; counters
+                        *self.extra_hps.values()):  # update in one step
+                if hps is None:
+                    continue
+                if hps.consumer is not None:
+                    applied += hps.apply_updates()
+                if sweep:
+                    hps.schedule_refresh()
+                if hps.refresh_backlog():
+                    refreshed += hps.refresh_step(self.refresh_budget)
+            if applied or refreshed:
+                with self._stats_lock:
+                    self.updates_applied += applied
+                    self.rows_refreshed += refreshed
+            if self.on_tick is not None:
+                self.on_tick()
 
     # -- queued/batched path --------------------------------------------------------
 
@@ -461,44 +476,60 @@ class InferenceServer:
                                      self.max_batch, est)
 
     def _coalesce(self, first
-                  ) -> Optional[Tuple[list, np.ndarray, np.ndarray]]:
+                  ) -> Optional[Tuple[int, list, np.ndarray, np.ndarray]]:
         """Drain the queue behind ``first`` into one coalesced request
-        group (the batcher of the paper's Figure 2 — one group is one
-        device batch), bounded by ``max_batch`` rows or, with an SLO
-        declared, by the oldest request's remaining slack
-        (:func:`deadline_batch_target`; the group may overshoot the
-        target by at most the last drained request, since a drained
-        request is never re-queued). An expired head is shed with the
+        group ``(group id, requests, dense, cat)`` (the batcher of the
+        paper's Figure 2 — one group is one device batch), bounded by
+        ``max_batch`` rows or, with an SLO declared, by the oldest
+        request's remaining slack (:func:`deadline_batch_target`; the
+        group may overshoot the target by at most the last drained
+        request, since a drained request is never re-queued). An expired head is shed with the
         typed rejection instead of served late. Requests that cannot be
         concatenated (mismatched widths) get the error delivered to
         their handles here and ``None`` comes back — the serve loop must
-        keep running."""
-        while self._expired(first):
-            self._put_rejection(first, f"deadline expired "
-                                       f"(slo {self.slo_ms}ms)")
-            with self._stats_lock:
-                self.requests_expired += 1
-            try:
-                first = self._q.get_nowait()
-            except queue.Empty:
-                return None
-        reqs = [first]
-        rows = first.dense.shape[0]
-        target = self._batch_target(first)
-        while rows < target:
-            try:
-                nxt = self._q.get_nowait()
-            except queue.Empty:
-                break
-            reqs.append(nxt)
-            rows += nxt.dense.shape[0]
+        keep running. Every request drained, served or shed, adds its
+        wait in the queue to ``queue_wait_s``."""
+        self._group += 1
+        drained = [first]
         try:
-            dense = np.concatenate([r.dense for r in reqs])
-            cat = np.concatenate([r.cat for r in reqs])
-        except Exception as exc:
-            self._deliver_error(reqs, exc)
-            return None
-        return reqs, dense, cat
+            with tracing.span("server.coalesce", group=self._group):
+                while self._expired(first):
+                    self._put_rejection(first, f"deadline expired "
+                                               f"(slo {self.slo_ms}ms)")
+                    with self._stats_lock:
+                        self.requests_expired += 1
+                    try:
+                        first = self._q.get_nowait()
+                    except queue.Empty:
+                        return None
+                    drained.append(first)
+                reqs = [first]
+                rows = first.dense.shape[0]
+                target = self._batch_target(first)
+                while rows < target:
+                    try:
+                        nxt = self._q.get_nowait()
+                    except queue.Empty:
+                        break
+                    reqs.append(nxt)
+                    drained.append(nxt)
+                    rows += nxt.dense.shape[0]
+                try:
+                    dense = np.concatenate([r.dense for r in reqs])
+                    cat = np.concatenate([r.cat for r in reqs])
+                except Exception as exc:
+                    self._deliver_error(reqs, exc)
+                    return None
+                return self._group, reqs, dense, cat
+        finally:
+            self._record_drained(drained)
+
+    def _record_drained(self, drained: List[_Req]) -> None:
+        now = time.perf_counter()
+        wait = sum(now - r.t_enq for r in drained)
+        with self._stats_lock:
+            self.queue_wait_s += wait
+            self.requests_drained += len(drained)
 
     def _deliver(self, reqs: list, preds: np.ndarray) -> None:
         off = 0
@@ -538,7 +569,7 @@ class InferenceServer:
         burst ends when the request queue goes empty; the pipeline then
         drains in order.
         """
-        fifo: deque = deque()   # (reqs, dense, t0) in admission order
+        fifo: deque = deque()   # (group, reqs, dense, t0) in order
         head = [first]
 
         def cats():
@@ -555,11 +586,11 @@ class InferenceServer:
                 group = self._coalesce(nxt)
                 if group is None:           # un-concatenatable: errors
                     continue                # already delivered
-                reqs, dense, cat = group
+                gid, reqs, dense, cat = group
                 if dense.shape[0] == 0:     # degenerate empty group
                     self._deliver(reqs, np.zeros((0,), np.float32))
                     continue
-                fifo.append((reqs, dense, time.perf_counter()))
+                fifo.append((gid, reqs, dense, time.perf_counter()))
                 yield cat
 
         def group_src(src, key):
@@ -586,16 +617,18 @@ class InferenceServer:
                 group_src(next(srcs), key), self._group_hot(key),
                 materialize=False))
 
-        in_flight: deque = deque()          # (reqs, t0, device preds)
+        in_flight: deque = deque()   # (group, reqs, t0, device preds)
         current = None                      # group between fifo/in_flight
         try:
             for vals in zip(*streams):
                 emb = vals[0]
                 wide = vals[1] if n_wide else None
                 extras = dict(zip(extra_names, vals[1 + n_wide:]))
-                current = fifo.popleft()    # (reqs, dense, t0)
-                out = self._dense_forward(current[1], emb, wide, extras)
-                in_flight.append((current[0], current[2], out))
+                current = fifo.popleft()    # (group, reqs, dense, t0)
+                gid, reqs, dense, t0 = current
+                out = self._dense_forward(dense, emb, wide, extras,
+                                          group=gid)
+                in_flight.append((gid, reqs, t0, out))
                 current = None
                 self._refresh_tick()        # between pipeline stages
                 if len(in_flight) > 1:      # materialize one behind
@@ -604,28 +637,30 @@ class InferenceServer:
                 self._materialize(in_flight.popleft())
         except Exception as exc:            # a poisoned group kills the
             if current is not None:         # burst: surface the error to
-                self._deliver_error(current[0], exc)  # EVERY undelivered
-            for reqs, _, _ in in_flight:    # handle (the failing group's
+                self._deliver_error(current[1], exc)  # EVERY undelivered
+            for _, reqs, _, _ in in_flight:  # handle (the failing group's
                 self._deliver_error(reqs, exc)   # own included) instead
-            for reqs, _, _ in fifo:         # of hanging callers
+            for _, reqs, _, _ in fifo:      # of hanging callers
                 self._deliver_error(reqs, exc)
 
     def _materialize(self, item) -> None:
-        reqs, t0, pred = item
-        try:
-            preds = np.asarray(pred)        # the one sync point per group
-        except Exception as exc:            # deferred device error: this
-            self._deliver_error(reqs, exc)  # group's handles first, the
-            raise                           # burst handler does the rest
-        self._record_latency(t0, rows=len(preds))
-        self._deliver(reqs, preds)
+        gid, reqs, t0, pred = item
+        with tracing.span("server.materialize", group=gid):
+            try:
+                preds = np.asarray(pred)    # the one sync point per group
+            except Exception as exc:        # deferred device error: this
+                self._deliver_error(reqs, exc)  # group's handles first,
+                raise                       # the burst handler the rest
+            self._record_latency(t0, rows=len(preds))
+            self._deliver(reqs, preds)
 
     # -- serve loop -----------------------------------------------------------------
 
     def _serve_loop(self):
         while not self._stop.is_set():
             try:
-                first = self._q.get(timeout=0.05)
+                with tracing.span("server.idle_wait", group=self._group):
+                    first = self._q.get(timeout=0.05)
             except queue.Empty:
                 self._refresh_tick()     # idle: drain the refresh backlog
                 continue
@@ -636,7 +671,7 @@ class InferenceServer:
             if group is None:               # errors already delivered
                 self._refresh_tick()
                 continue
-            reqs, dense, cat = group
+            _, reqs, dense, cat = group
             try:
                 if self.engine == "stage_sync":
                     preds = self._predict_stage_sync(dense, cat)
@@ -685,6 +720,9 @@ class InferenceServer:
                 self.requests_shed += shed
 
     def latency_percentiles(self) -> Dict[str, float]:
+        """Percentiles of the groups' service times (``_record_latency``):
+        from a group's drain, not from a request's admission. The queue
+        wait before the drain is in ``counters()``' ``queue_wait_s``."""
         with self._stats_lock:
             hist = self.latency_hist.snapshot()
         if hist.count == 0:
@@ -706,6 +744,8 @@ class InferenceServer:
             self.requests_delivered = 0
             self.requests_expired = 0
             self.slo_violations = 0
+            self.queue_wait_s = 0.0
+            self.requests_drained = 0
         with self._admit_lock:
             self.requests_shed = 0
 
@@ -721,7 +761,7 @@ class InferenceServer:
             out.update(hps.consumer.last_versions)
         return out
 
-    def counters(self) -> Dict[str, int]:
+    def counters(self) -> Dict[str, float]:
         """Lock-consistent snapshot of the serving counters."""
         with self._stats_lock:
             out = {"updates_applied": self.updates_applied,
@@ -729,7 +769,9 @@ class InferenceServer:
                    "groups_served": self.latency_hist.count,
                    "requests_delivered": self.requests_delivered,
                    "requests_expired": self.requests_expired,
-                   "slo_violations": self.slo_violations}
+                   "slo_violations": self.slo_violations,
+                   "queue_wait_s": self.queue_wait_s,
+                   "requests_drained": self.requests_drained}
         with self._admit_lock:
             out["requests_shed"] = self.requests_shed
         return out

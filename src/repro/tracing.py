@@ -1,0 +1,66 @@
+"""Program spans on the profiler's clock.
+
+``span(name, **ids)`` is a ``jax.profiler.TraceAnnotation`` named
+``"repro/" + name`` whose keyword ids become the event's stats, so every
+span lands in the same ``.xplane.pb`` as the device's ``XLA Modules`` and
+``XLA Ops`` lines, on one clock, on the host thread that ran it. JAX's own
+``backend_compile_and_load`` event nests inside the span around the call
+that compiled. With no profiler session active a span records nothing and
+costs about a microsecond, so the spans stay in the code unconditionally.
+They sit at group, table, step or fetch granularity, never per row or per
+request.
+
+Spans, with their id tags:
+
+Serving (``serve/server.py``); ``group`` is the server's sequence number
+of a request group (-1 for a ``predict`` call made outside the stream
+engine's groups); ``refresh_tick`` and ``idle_wait`` carry the latest
+group coalesced:
+
+  server.coalesce        draining the queue into one request group
+  server.dense_forward   the jitted dense net's dispatch and the sigmoid
+  server.materialize     the one host sync per group and the delivery
+  server.refresh_tick    bus polling and one bounded L1 refresh chunk
+  server.idle_wait       the serve loop waiting for a first request
+
+HPS (``core/hps/hps.py``, ``payload_store.py``); ``table`` is the table's
+name, ``rows`` a row count:
+
+  hps.probe              one table's host index probe, on an HPS host
+                         worker in the pipelined engines (``table``)
+  hps.miss_fetch         the L2 query, L3 fetch and L2 promotion of one
+                         table's misses (``table``)
+  hps.device_stage       one table's deferred scatter flush and slot
+                         transfer (``table``)
+  hps.l1_scatter         the payload scatter, inside ``hps.device_stage``
+                         or inside a probe or refresh that flushes
+                         (``rows``: the scattered rows, before bucketing)
+  hps.pooled_stack       the pooled-gather dispatch, its ``[:b]`` slice and
+                         the overflow fix (``rows``: the group's rows)
+
+Training (``train/trainer.py``); ``step`` is the trainer's step number:
+
+  train.step             one loop iteration, holding the five below
+  train.data             the host batch, ``data_fn(step)``
+  train.put_batch        the host-to-device transfer
+  train.dispatch         the train step's dispatch
+  train.sync             the wait for the step's loss
+  train.checkpoint       handing a checkpoint to the async saver
+
+Counters (``InferenceServer.counters()``), summed since the server was
+made or ``reset_serving_stats`` last ran:
+
+  queue_wait_s           over drained requests (served or shed on
+                         expiry): drain time minus admission time
+  requests_drained       the requests that sum covers
+"""
+from __future__ import annotations
+
+import jax
+
+PREFIX = "repro/"
+
+
+def span(name: str, **ids) -> jax.profiler.TraceAnnotation:
+    """A host span named ``PREFIX + name`` with ``ids`` as its stats."""
+    return jax.profiler.TraceAnnotation(PREFIX + name, **ids)

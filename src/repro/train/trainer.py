@@ -20,6 +20,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import put_batch
 from repro.train import checkpoint as ckpt_lib
@@ -138,20 +139,28 @@ class Trainer:
         step = start
         while step < num_steps:
             try:
-                if self.failure_injector is not None:
-                    self.failure_injector(step)
-                t0 = time.perf_counter()
-                batch = put_batch(self.data_fn(step), self.mesh)
-                params, opt_state, metrics = self._step(params, opt_state,
-                                                        batch)
-                loss = float(metrics["loss"])
-                dt = time.perf_counter() - t0
-                self._watch_stragglers(dt)
-                history.append({"step": step, "loss": loss, "time": dt})
-                if log_every and step % log_every == 0:
-                    print(f"step {step}: loss={loss:.4f} ({dt*1e3:.1f} ms)")
-                if self.saver and step % self.ckpt_interval == 0:
-                    self.save(step, params, opt_state)
+                with tracing.span("train.step", step=step):
+                    if self.failure_injector is not None:
+                        self.failure_injector(step)
+                    t0 = time.perf_counter()
+                    with tracing.span("train.data", step=step):
+                        host = self.data_fn(step)
+                    with tracing.span("train.put_batch", step=step):
+                        batch = put_batch(host, self.mesh)
+                    with tracing.span("train.dispatch", step=step):
+                        params, opt_state, metrics = self._step(
+                            params, opt_state, batch)
+                    with tracing.span("train.sync", step=step):
+                        loss = float(metrics["loss"])
+                    dt = time.perf_counter() - t0
+                    self._watch_stragglers(dt)
+                    history.append({"step": step, "loss": loss, "time": dt})
+                    if log_every and step % log_every == 0:
+                        print(f"step {step}: loss={loss:.4f} "
+                              f"({dt*1e3:.1f} ms)")
+                    if self.saver and step % self.ckpt_interval == 0:
+                        with tracing.span("train.checkpoint", step=step):
+                            self.save(step, params, opt_state)
                 step += 1
             except (ckpt_lib.os.error, RuntimeError, ValueError) as e:
                 # node failure path: restore + replay
@@ -163,7 +172,8 @@ class Trainer:
                     rstep, params, opt_state = restored
                     step = rstep + 1
         if self.saver:
-            self.save(num_steps - 1, params, opt_state)
+            with tracing.span("train.checkpoint", step=num_steps - 1):
+                self.save(num_steps - 1, params, opt_state)
             self.saver.wait()
         return {"params": params, "opt_state": opt_state,
                 "history": history, "stragglers": self.stragglers}
