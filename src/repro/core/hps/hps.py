@@ -52,6 +52,13 @@ from repro.core.hps.volatile_db import VolatileDB
 from repro.kernels import ops
 
 
+def bucket_rows(b: int) -> int:
+    """The power-of-two row bucket a ``b``-row query runs at (0 for 0):
+    the pooled gather, and the server's dense net after it, compile once
+    per bucket rather than once per row count."""
+    return 1 << (b - 1).bit_length() if b > 0 else 0
+
+
 @functools.partial(jax.jit, static_argnames=("combiners", "apply_mean",
                                              "shards", "mesh", "axis"))
 def _pooled_stack(payloads: Tuple[tuple, ...],
@@ -279,8 +286,12 @@ class HPS:
                   slot_blocks: List[jax.Array],
                   blocks: List[np.ndarray],
                   overflow: List[Tuple[int, np.ndarray, np.ndarray, int]],
-                  b: int) -> jax.Array:
-        """The single jitted pooled-stack dispatch (+ rare overflow fix)."""
+                  b: int, padded: bool = False) -> jax.Array:
+        """The single jitted pooled-stack dispatch (+ rare overflow fix).
+
+        The block comes out at the slot blocks' bucket ``[bp, T, D]``;
+        ``padded=False`` slices it to the query's ``b`` rows. Padded rows
+        hold only -1 slots, so they pool to 0 under either combiner."""
         with tracing.span("hps.pooled_stack", rows=b):
             combiners = tuple("mean" if t.combiner == "mean" else "sum"
                               for t in self.tables)
@@ -288,29 +299,32 @@ class HPS:
                 _pooled_stack, tuple(payloads), tuple(slot_blocks),
                 combiners, shards=self.cache_shards, mesh=self.cache_mesh)
             if not overflow:
-                return stack()[:b]
+                out = stack()
+                return out if padded else out[:b]
 
             # rare path: some ids exceeded L1 evictable capacity; add
             # their contribution host-side, then apply the mean
-            # denominators exactly
-            out = stack(apply_mean=False)[:b]
-            dim = self.tables[0].dim
-            corr = np.zeros((b, len(self.tables), dim), np.float32)
+            # denominators exactly (zeros and ones on the padded rows)
+            out = stack(apply_mean=False)
+            bp = out.shape[0]
+            corr = np.zeros((bp, len(self.tables), self.tables[0].dim),
+                            np.float32)
             for ti, ov_idx, ov_rows, h in overflow:
                 np.add.at(corr[:, ti, :], ov_idx // h, ov_rows)
             out = out + jnp.asarray(corr)
             mean_mask = np.asarray([c == "mean" for c in combiners])
             if mean_mask.any():
-                denom = np.stack(
+                denom = np.ones((bp, len(self.tables), 1), np.float32)
+                denom[:b, :, 0] = np.stack(
                     [np.maximum((blk >= 0).sum(axis=1), 1)
-                     for blk in blocks],
-                    axis=1).astype(np.float32)[:, :, None]
+                     for blk in blocks], axis=1)
                 out = jnp.where(jnp.asarray(mean_mask)[None, :, None],
                                 out / jnp.asarray(denom), out)
-            return out
+            return out if padded else out[:b]
 
     def lookup(self, cat: np.ndarray, hotness: Optional[List[int]] = None,
-               *, pipelined: bool = False) -> jax.Array:
+               *, pipelined: bool = False,
+               padded: bool = False) -> jax.Array:
         """``cat [B, T, H]`` or ``[B, sum(hotness)]`` (-1 pad) -> pooled
         ``[B, T, D]`` on device, honoring each table's combiner.
 
@@ -326,6 +340,11 @@ class HPS:
         *t+1* is being probed while table *t*'s scatter is in flight.
         Results are identical to the sequential path — each table's plan
         carries a lock-consistent payload snapshot.
+
+        ``padded=True`` returns the block at its bucket,
+        ``[bucket_rows(B), T, D]``, with zeros past row ``B``: the server
+        runs its dense net at the bucket too, so no program in the step
+        compiles per row count.
         """
         cat = np.asarray(cat)
         blocks = self._split_query(cat, hotness)
@@ -334,7 +353,7 @@ class HPS:
         b = cat.shape[0]
         if b == 0:
             return jnp.zeros((0, T, self.tables[0].dim), jnp.float32)
-        bp = 1 << (b - 1).bit_length()
+        bp = bucket_rows(b)
 
         slot_blocks: List[jax.Array] = []
         payloads: List[jax.Array] = []
@@ -356,16 +375,19 @@ class HPS:
                 self._collect_plan(ti, self._probe(ti, blocks), b, bp,
                                    blocks, slot_blocks, payloads, overflow)
 
-        return self._finalize(payloads, slot_blocks, blocks, overflow, b)
+        return self._finalize(payloads, slot_blocks, blocks, overflow, b,
+                              padded)
 
     def lookup_stage_sync(self, cat: np.ndarray,
-                          hotness: Optional[List[int]] = None) -> jax.Array:
+                          hotness: Optional[List[int]] = None, *,
+                          padded: bool = False) -> jax.Array:
         """Fully stage-synchronous lookup: BLOCK on each table's device
         scatter before the next host probe, and block on the pooled
         stack before returning — zero overlap of any kind, not even
         XLA's async dispatch. The no-overlap reference engine the
         pipelining benchmarks (and the ``stage_sync`` server engine)
-        compare against; bit-identical outputs to :meth:`lookup`."""
+        compare against; bit-identical outputs to :meth:`lookup`, whose
+        ``padded`` it takes."""
         cat = np.asarray(cat)
         blocks = self._split_query(cat, hotness)
         self._check_dims()
@@ -373,7 +395,7 @@ class HPS:
         if b == 0:
             return jnp.zeros((0, len(self.tables), self.tables[0].dim),
                              jnp.float32)
-        bp = 1 << (b - 1).bit_length()
+        bp = bucket_rows(b)
         slot_blocks: List[jax.Array] = []
         payloads: List[jax.Array] = []
         overflow: List[Tuple[int, np.ndarray, np.ndarray, int]] = []
@@ -383,7 +405,8 @@ class HPS:
                                          payloads, overflow)
             jax.block_until_ready(payload)             # no overlap
         return jax.block_until_ready(
-            self._finalize(payloads, slot_blocks, blocks, overflow, b))
+            self._finalize(payloads, slot_blocks, blocks, overflow, b,
+                           padded))
 
     def _timed_probe(self, ti: int, blocks: List[np.ndarray],
                      rec: List[float]) -> LookupPlan:
@@ -397,7 +420,8 @@ class HPS:
     def lookup_stream(self, cats: Iterable[np.ndarray],
                       hotness: Optional[List[int]] = None, *,
                       depth: Optional[int] = None, max_depth: int = 8,
-                      materialize: bool = True) -> Iterator:
+                      materialize: bool = True,
+                      padded: bool = False) -> Iterator:
         """Serve a stream of queries through the two-stage pipeline,
         yielding ``[B, T, D]`` pooled outputs in order.
 
@@ -427,6 +451,7 @@ class HPS:
         and owns the delay point itself, so the prediction (not the
         embedding) is what finally synchronizes the pipeline and NOTHING
         bounces through host memory between lookup and dense compute.
+        ``padded`` is :meth:`lookup`'s.
         """
         self._check_dims()
         pool = self._host_worker()
@@ -464,13 +489,13 @@ class HPS:
                 b, blocks, futs, rec = pending.popleft()
                 plans = [f.result() for f in futs]
                 t0 = time.perf_counter()    # host-stage wait excluded
-                bp = 1 << (b - 1).bit_length()
+                bp = bucket_rows(b)
                 slot_blocks, payloads, overflow = [], [], []
                 for ti, plan in enumerate(plans):
                     self._collect_plan(ti, plan, b, bp, blocks,
                                        slot_blocks, payloads, overflow)
                 out = self._finalize(payloads, slot_blocks, blocks,
-                                     overflow, b)
+                                     overflow, b, padded)
                 admit()                     # next query probes first ...
                 if not materialize:         # ... caller owns the delay
                     yield out

@@ -15,7 +15,11 @@ probes (and their remote L2/L3 miss fetches) run on the HPS host
 workers. The only host sync point per query is the prediction itself.
 Predictions are bit-identical to the unpipelined path: the per-plan
 payload snapshots make the lookup machinery order-independent, and the
-dense net is the same jitted function either way. Two reference engines
+dense net is the same jitted function either way. Every engine runs a
+group at its power-of-two row bucket (``hps.bucket_rows``, the pooled
+gather's) from pooled block to probability, so the dense net compiles
+once per bucket, not once per group; the host keeps the group's own rows
+after the sync. Two reference engines
 remain selectable: ``"sync"`` (drain -> one blocking ``predict`` per
 group — the old loop, where XLA async dispatch still overlaps device
 work behind the host) and ``"stage_sync"`` (every device stage blocked
@@ -77,7 +81,7 @@ import numpy as np
 
 from repro import tracing
 from repro.configs.base import EmbeddingTableConfig, RecsysConfig
-from repro.core.hps.hps import HPS
+from repro.core.hps.hps import HPS, bucket_rows
 from repro.core.hps.message_bus import MessageBus
 from repro.core.hps.persistent_db import PersistentDB
 from repro.core.hps.volatile_db import VolatileDB
@@ -162,6 +166,8 @@ class InferenceServer:
         "slo_violations": "_stats_lock",
         "queue_wait_s": "_stats_lock",
         "requests_drained": "_stats_lock",
+        "rows_padded": "_stats_lock",
+        "_dense_shapes": "_stats_lock",
         "_service_ms_per_row": "_stats_lock",
         "_closed": "_admit_lock",
         "requests_shed": "_admit_lock",
@@ -221,6 +227,10 @@ class InferenceServer:
         #: many requests that sum covers (``repro.tracing`` lists both)
         self.queue_wait_s = 0.0
         self.requests_drained = 0
+        #: padding rows the dense net computed (groups run at their
+        #: ``bucket_rows`` bucket), and the row counts it was dispatched at
+        self.rows_padded = 0
+        self._dense_shapes: set = set()
         #: sequence number of the latest request group coalesced; only
         #: the serve-loop thread touches it (the id of the spans' groups)
         self._group = -1
@@ -231,18 +241,14 @@ class InferenceServer:
         self._closed = False
         self.requests_shed = 0
         self._last_poll = time.monotonic()
+        # the dense net and its sigmoid, one program per row bucket
         if self.extra_hps:
-            self._predict = jax.jit(
-                lambda p, d, e, w, x: model.apply_dense(p, d, e, w,
-                                                        extras=x))
-            self._predict_nowide = jax.jit(
-                lambda p, d, e, x: model.apply_dense(p, d, e, None,
-                                                     extras=x))
+            net = lambda p, d, e, w, x: jax.nn.sigmoid(  # noqa: E731
+                model.apply_dense(p, d, e, w, extras=x))
         else:
-            self._predict = jax.jit(
-                lambda p, d, e, w: model.apply_dense(p, d, e, w))
-            self._predict_nowide = jax.jit(
-                lambda p, d, e: model.apply_dense(p, d, e, None))
+            net = lambda p, d, e, w, x: jax.nn.sigmoid(  # noqa: E731
+                model.apply_dense(p, d, e, w))
+        self._predict = jax.jit(net)
         self._q: queue.Queue = queue.Queue(maxsize=queue_depth or 0)
         self._stop = threading.Event()
         self._worker: Optional[threading.Thread] = None
@@ -315,75 +321,73 @@ class InferenceServer:
     def _dense_forward(self, dense: np.ndarray, emb: jax.Array,
                        wide: Optional[jax.Array],
                        extras: Optional[Dict[str, jax.Array]] = None,
-                       group: int = -1) -> jax.Array:
-        """The one jitted dense-net dispatch + host-side sigmoid — shared
-        by every engine so outputs are bit-identical across them."""
+                       group: int = -1, *, rows: int) -> jax.Array:
+        """The one jitted dense-net-and-sigmoid dispatch — shared by every
+        engine so outputs are bit-identical across them.
+
+        ``emb`` (and ``wide``, ``extras``) arrive at the lookup's bucket
+        ``bucket_rows(rows)``; ``dense`` is padded to it here, on the
+        host, so the program compiles once per bucket. The predictions
+        come back at the bucket too: callers keep the first ``rows``
+        after the sync. Every served model computes each row on its own,
+        so padding changes no real row's answer."""
         with tracing.span("server.dense_forward", group=group):
-            d = jnp.asarray(dense)
-            if self.extra_hps:
-                if wide is not None:
-                    out = self._predict(self.dense_params, d, emb, wide,
-                                        extras or {})
-                else:
-                    out = self._predict_nowide(self.dense_params, d, emb,
-                                               extras or {})
-            elif wide is not None:
-                out = self._predict(self.dense_params, d, emb, wide)
-            else:
-                out = self._predict_nowide(self.dense_params, d, emb)
-            return jax.nn.sigmoid(out)
+            b = dense.shape[0]
+            if b != rows:
+                raise ValueError(f"dense has {b} rows for {rows} rows "
+                                 f"of cat")
+            bp = bucket_rows(b)
+            if bp != b:
+                dense = np.pad(dense, [(0, bp - b)]
+                               + [(0, 0)] * (dense.ndim - 1))
+            out = self._predict(self.dense_params, jnp.asarray(dense), emb,
+                                wide, extras or {})
+            with self._stats_lock:
+                self.rows_padded += bp - b
+                self._dense_shapes.add(bp)
+            return out
+
+    def _lookups(self, cat: np.ndarray, lookup: Callable
+                 ) -> Tuple[jax.Array, Optional[jax.Array],
+                            Dict[str, jax.Array]]:
+        """Every embedding group's bucket-shaped block for one request
+        group, by ``lookup(hps, cat, hotness)``: the deep block, the wide
+        twins (which read the deep group's cat columns) and the extras."""
+        dcat = self._group_cat(cat, "embedding")
+        dhot = self._group_hot("embedding")
+        emb = lookup(self.hps, dcat, dhot)
+        wide = None
+        if self.wide_hps is not None:
+            wide = lookup(self.wide_hps, dcat, dhot)
+        extras = {
+            name: lookup(hps, self._group_cat(cat, f"embedding@{name}"),
+                         self._group_hot(f"embedding@{name}"))
+            for name, hps in self.extra_hps.items()}
+        return emb, wide, extras
 
     def predict(self, dense: np.ndarray, cat: np.ndarray) -> np.ndarray:
         t0 = time.perf_counter()
-        dcat = self._group_cat(cat, "embedding")
-        dhot = self._group_hot("embedding")
-        emb = self.hps.lookup(dcat, dhot,
-                              pipelined=len(self.hps.tables) > 1)
-        wide = None
-        if self.wide_hps is not None:       # wide twins share the deep
-            wide = self.wide_hps.lookup(    # group's cat columns
-                dcat, dhot,
-                pipelined=len(self.wide_hps.tables) > 1)
-        extras = {
-            name: hps.lookup(self._group_cat(cat, f"embedding@{name}"),
-                             self._group_hot(f"embedding@{name}"),
-                             pipelined=len(hps.tables) > 1)
-            for name, hps in self.extra_hps.items()}
-        out = np.asarray(self._dense_forward(dense, emb, wide, extras))
-        self._record_latency(t0, rows=dense.shape[0])
+        emb, wide, extras = self._lookups(
+            cat, lambda hps, c, h: hps.lookup(
+                c, h, pipelined=len(hps.tables) > 1, padded=True))
+        out = self._dense_forward(dense, emb, wide, extras,
+                                  rows=cat.shape[0])
+        out = np.asarray(out)[:cat.shape[0]]
+        self._record_latency(t0, rows=len(out))
         return out
 
     def _predict_stage_sync(self, dense: np.ndarray,
                             cat: np.ndarray) -> np.ndarray:
         """The no-overlap reference: every embedding device stage blocks
-        before the next host stage, the dense net blocks before the
-        sigmoid — nothing is left to XLA's async dispatch."""
+        before the next host stage, and the dense net blocks before its
+        answer is read — nothing is left to XLA's async dispatch."""
         t0 = time.perf_counter()
-        dcat = self._group_cat(cat, "embedding")
-        dhot = self._group_hot("embedding")
-        emb = self.hps.lookup_stage_sync(dcat, dhot)
-        wide = None
-        if self.wide_hps is not None:
-            wide = self.wide_hps.lookup_stage_sync(dcat, dhot)
-        extras = {
-            name: hps.lookup_stage_sync(
-                self._group_cat(cat, f"embedding@{name}"),
-                self._group_hot(f"embedding@{name}"))
-            for name, hps in self.extra_hps.items()}
-        d = jnp.asarray(dense)
-        if self.extra_hps:
-            if wide is not None:
-                out = self._predict(self.dense_params, d, emb, wide,
-                                    extras)
-            else:
-                out = self._predict_nowide(self.dense_params, d, emb,
-                                           extras)
-        elif wide is not None:
-            out = self._predict(self.dense_params, d, emb, wide)
-        else:
-            out = self._predict_nowide(self.dense_params, d, emb)
-        out = np.asarray(jax.nn.sigmoid(jax.block_until_ready(out)))
-        self._record_latency(t0, rows=dense.shape[0])
+        emb, wide, extras = self._lookups(
+            cat, lambda hps, c, h: hps.lookup_stage_sync(c, h, padded=True))
+        out = jax.block_until_ready(self._dense_forward(
+            dense, emb, wide, extras, rows=cat.shape[0]))
+        out = np.asarray(out)[:cat.shape[0]]
+        self._record_latency(t0, rows=len(out))
         return out
 
     # -- refresh scheduling (runs on the serve loop, between batches) -------------
@@ -569,7 +573,7 @@ class InferenceServer:
         burst ends when the request queue goes empty; the pipeline then
         drains in order.
         """
-        fifo: deque = deque()   # (group, reqs, dense, t0) in order
+        fifo: deque = deque()   # (group, reqs, dense, rows, t0) in order
         head = [first]
 
         def cats():
@@ -590,7 +594,8 @@ class InferenceServer:
                 if dense.shape[0] == 0:     # degenerate empty group
                     self._deliver(reqs, np.zeros((0,), np.float32))
                     continue
-                fifo.append((gid, reqs, dense, time.perf_counter()))
+                fifo.append((gid, reqs, dense, cat.shape[0],
+                             time.perf_counter()))
                 yield cat
 
         def group_src(src, key):
@@ -606,29 +611,30 @@ class InferenceServer:
         srcs = iter(itertools.tee(cats(), 1 + n_wide + len(extra_names)))
         streams = [self.hps.lookup_stream(
             group_src(next(srcs), "embedding"),
-            self._group_hot("embedding"), materialize=False)]
+            self._group_hot("embedding"), materialize=False, padded=True)]
         if self.wide_hps is not None:       # wide twins read the deep
             streams.append(self.wide_hps.lookup_stream(  # group's columns
                 group_src(next(srcs), "embedding"),
-                self._group_hot("embedding"), materialize=False))
+                self._group_hot("embedding"), materialize=False,
+                padded=True))
         for name in extra_names:
             key = f"embedding@{name}"
             streams.append(self.extra_hps[name].lookup_stream(
                 group_src(next(srcs), key), self._group_hot(key),
-                materialize=False))
+                materialize=False, padded=True))
 
-        in_flight: deque = deque()   # (group, reqs, t0, device preds)
+        in_flight: deque = deque()   # (group, reqs, rows, t0, device preds)
         current = None                      # group between fifo/in_flight
         try:
             for vals in zip(*streams):
                 emb = vals[0]
                 wide = vals[1] if n_wide else None
                 extras = dict(zip(extra_names, vals[1 + n_wide:]))
-                current = fifo.popleft()    # (group, reqs, dense, t0)
-                gid, reqs, dense, t0 = current
+                current = fifo.popleft()
+                gid, reqs, dense, rows, t0 = current
                 out = self._dense_forward(dense, emb, wide, extras,
-                                          group=gid)
-                in_flight.append((gid, reqs, t0, out))
+                                          group=gid, rows=rows)
+                in_flight.append((gid, reqs, rows, t0, out))
                 current = None
                 self._refresh_tick()        # between pipeline stages
                 if len(in_flight) > 1:      # materialize one behind
@@ -638,20 +644,21 @@ class InferenceServer:
         except Exception as exc:            # a poisoned group kills the
             if current is not None:         # burst: surface the error to
                 self._deliver_error(current[1], exc)  # EVERY undelivered
-            for _, reqs, _, _ in in_flight:  # handle (the failing group's
+            for _, reqs, *_ in in_flight:   # handle (the failing group's
                 self._deliver_error(reqs, exc)   # own included) instead
-            for _, reqs, _, _ in fifo:      # of hanging callers
+            for _, reqs, *_ in fifo:        # of hanging callers
                 self._deliver_error(reqs, exc)
 
     def _materialize(self, item) -> None:
-        gid, reqs, t0, pred = item
+        gid, reqs, rows, t0, pred = item
         with tracing.span("server.materialize", group=gid):
             try:
                 preds = np.asarray(pred)    # the one sync point per group
             except Exception as exc:        # deferred device error: this
                 self._deliver_error(reqs, exc)  # group's handles first,
                 raise                       # the burst handler the rest
-            self._record_latency(t0, rows=len(preds))
+            preds = preds[:rows]            # the bucket's real rows
+            self._record_latency(t0, rows=rows)
             self._deliver(reqs, preds)
 
     # -- serve loop -----------------------------------------------------------------
@@ -746,6 +753,8 @@ class InferenceServer:
             self.slo_violations = 0
             self.queue_wait_s = 0.0
             self.requests_drained = 0
+            self.rows_padded = 0
+            self._dense_shapes = set()
         with self._admit_lock:
             self.requests_shed = 0
 
@@ -771,7 +780,9 @@ class InferenceServer:
                    "requests_expired": self.requests_expired,
                    "slo_violations": self.slo_violations,
                    "queue_wait_s": self.queue_wait_s,
-                   "requests_drained": self.requests_drained}
+                   "requests_drained": self.requests_drained,
+                   "rows_padded": self.rows_padded,
+                   "dense_shapes": len(self._dense_shapes)}
         with self._admit_lock:
             out["requests_shed"] = self.requests_shed
         return out

@@ -18,7 +18,8 @@ engine's groups); ``refresh_tick`` and ``idle_wait`` carry the latest
 group coalesced:
 
   server.coalesce        draining the queue into one request group
-  server.dense_forward   the jitted dense net's dispatch and the sigmoid
+  server.dense_forward   the jitted dense net's dispatch, sigmoid inside,
+                         at the group's row bucket
   server.materialize     the one host sync per group and the delivery
   server.refresh_tick    bus polling and one bounded L1 refresh chunk
   server.idle_wait       the serve loop waiting for a first request
@@ -35,8 +36,9 @@ name, ``rows`` a row count:
   hps.l1_scatter         the payload scatter, inside ``hps.device_stage``
                          or inside a probe or refresh that flushes
                          (``rows``: the scattered rows, before bucketing)
-  hps.pooled_stack       the pooled-gather dispatch, its ``[:b]`` slice and
-                         the overflow fix (``rows``: the group's rows)
+  hps.pooled_stack       the pooled-gather dispatch, the overflow fix and,
+                         for callers that want ``b`` rows, the ``[:b]``
+                         slice (``rows``: the group's rows)
 
 Training (``train/trainer.py``); ``step`` is the trainer's step number:
 
@@ -53,6 +55,10 @@ made or ``reset_serving_stats`` last ran:
   queue_wait_s           over drained requests (served or shed on
                          expiry): drain time minus admission time
   requests_drained       the requests that sum covers
+  rows_padded            padding rows the dense net computed: each group
+                         runs at its power-of-two row bucket
+  dense_shapes           distinct row counts the dense net was dispatched
+                         at (a snapshot's count, not a sum)
 """
 from __future__ import annotations
 

@@ -144,6 +144,36 @@ def test_stream_engine_one_sync_per_group_zero_recompiles(served):
     assert summ["compiles"] == 0, summ  # ZERO post-warmup recompiles
 
 
+def test_stream_engine_zero_recompiles_within_a_bucket(served):
+    """Groups of 5, 6, 7 and 8 rows all run at the row bucket 8, from
+    pooled block to probability: once that bucket is warm (and the L1
+    holds every id below), they compile nothing, sync once each, and the
+    dense net sees one row count; the host trims each answer."""
+    m, server = served
+    base = SyntheticCTR(m.cfg, 8, seed=1200).batch(0)
+    sizes = (5, 6, 7, 8)
+    server.start()
+    try:
+        for _ in range(2):             # warm the bucket and the L1
+            out = server.submit(base["dense"], base["cat"]).get(timeout=120)
+            assert not isinstance(out, Exception)
+        server.reset_serving_stats()
+        with HotPathMonitor("stream-bucket") as mon:
+            for n in sizes:
+                out = server.submit(base["dense"][:n],
+                                    base["cat"][:n]).get(timeout=120)
+                assert isinstance(out, np.ndarray) and out.shape == (n,)
+    finally:
+        server.stop()
+    c = server.counters()
+    summ = mon.summary()
+    assert summ["compiles"] == 0, summ  # ZERO recompiles across row counts
+    assert summ["syncs"] == len(sizes), summ
+    assert c["groups_served"] == len(sizes)
+    assert c["dense_shapes"] == 1
+    assert c["rows_padded"] == sum(8 - n for n in sizes)
+
+
 def test_admission_control_preserves_hotpath_contract(served):
     """Arming the admission controller (bounded queue + declared SLO +
     deadline batching) must not change the hot path: the batch-cut

@@ -159,3 +159,58 @@ def test_message_roundtrip_serialization():
     i2, r2 = _deserialize(_serialize(ids, rows))
     np.testing.assert_array_equal(ids, i2)
     np.testing.assert_array_equal(rows, r2)
+
+
+# ---------------------------------------------------------------------------
+# bucket-shaped lookups (the server's dense net runs at the same bucket)
+# ---------------------------------------------------------------------------
+
+def test_bucket_rows_is_the_next_power_of_two():
+    from repro.core.hps.hps import bucket_rows
+    assert [bucket_rows(b) for b in (0, 1, 2, 3, 5, 8, 9, 1025)] \
+        == [0, 1, 2, 4, 8, 8, 16, 2048]
+
+
+@pytest.mark.parametrize("capacity", [64, 2], ids=["cached", "overflow"])
+@pytest.mark.parametrize("combiner", ["sum", "mean"])
+def test_padded_lookup_is_lookup_then_zero_rows(tmp_path, combiner,
+                                                capacity):
+    """``padded=True`` hands back the bucket-shaped block: its first
+    ``b`` rows are ``lookup()``'s, bit for bit, and the padded rows are
+    zero — through every engine, and through the overflow fix (a query
+    with more unique ids than the L1 can take)."""
+    pdb = PersistentDB(str(tmp_path / "pdb"))
+    rng = np.random.default_rng(7)
+    for name in ("t0", "t1"):
+        pdb.create_table("m", name, 100, 4, initial=rng.normal(
+            size=(100, 4)).astype(np.float32))
+    tabs = [EmbeddingTableConfig("t0", 100, 4, hotness=3,
+                                 combiner=combiner),
+            EmbeddingTableConfig("t1", 100, 4, hotness=3)]
+    hps = HPS("m", tabs, pdb, cache_capacity=capacity)
+    cat = rng.integers(0, 100, size=(5, 2, 3)).astype(np.int32)
+    cat[1, 0, 1:] = -1                       # a short row for the mean
+    overflowed = []
+    finalize = hps._finalize
+
+    def spy(payloads, slot_blocks, blocks, overflow, b, padded=False):
+        overflowed.append(len(overflow))
+        return finalize(payloads, slot_blocks, blocks, overflow, b, padded)
+
+    hps._finalize = spy
+    want = np.asarray(hps.lookup(cat))
+    assert want.shape == (5, 2, 4)
+    blocks = {
+        "lookup": hps.lookup(cat, padded=True),
+        "pipelined": hps.lookup(cat, pipelined=True, padded=True),
+        "stage_sync": hps.lookup_stage_sync(cat, padded=True),
+        "stream": next(iter(hps.lookup_stream([cat], materialize=False,
+                                              padded=True))),
+    }
+    hps.close()
+    assert all(overflowed) if capacity == 2 else not any(overflowed)
+    for engine, got in blocks.items():
+        got = np.asarray(got)
+        assert got.shape == (8, 2, 4), engine
+        np.testing.assert_array_equal(got[:5], want, err_msg=engine)
+        np.testing.assert_array_equal(got[5:], 0.0, err_msg=engine)

@@ -6,10 +6,13 @@ including under concurrent submits from multiple threads."""
 import queue
 import threading
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import Solver
+from repro.api import (DataReaderParams, DenseLayer, Input, Model, Solver,
+                       SparseEmbedding)
 from repro.data.synthetic import SyntheticCTR
 from repro.serve.server import InferenceServer
 
@@ -132,6 +135,69 @@ def test_stage_sync_engine_bitexact(served):
         np.testing.assert_array_equal(h.get(timeout=120), want)
     finally:
         ss.stop()
+
+
+def _engines_at(m, stream, sync, rows, seed):
+    """One request of ``rows`` rows through the stream engine, the
+    ``predict()`` path and the ``stage_sync`` engine: the three answers
+    must be bit-identical, and equal to the dense net applied to the
+    unpadded lookups to float tolerance."""
+    b = SyntheticCTR(m.cfg, rows, seed=seed).batch(0)
+    h = stream.submit(b["dense"], b["cat"])
+    stream.start()
+    try:
+        got = h.get(timeout=120)
+    finally:
+        stream.stop()
+    ss = InferenceServer(m.model, m.dense_params(), stream.hps,
+                         wide_hps=stream.wide_hps,
+                         extra_hps=stream.extra_hps, hotness=stream.hotness,
+                         max_batch=8, engine="stage_sync")
+    assert isinstance(got, np.ndarray) and got.shape == (rows,)
+    want = sync.predict(b["dense"], b["cat"])
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        ss._predict_stage_sync(b["dense"], b["cat"]), want)
+    emb, wide, extras = stream._lookups(
+        b["cat"], lambda hps, c, hot: hps.lookup(c, hot))
+    assert emb.shape[0] == rows                 # unpadded blocks
+    ref = jax.nn.sigmoid(m.model.apply_dense(
+        m.dense_params(), jnp.asarray(b["dense"]), emb, wide,
+        extras=extras or None))
+    np.testing.assert_allclose(want, np.asarray(ref), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("rows", [3, 5, 7])
+def test_engines_agree_at_uneven_row_counts(served, rows):
+    """Row counts that are not a power of two run padded to their bucket
+    (dense, deep and wide blocks alike) and come back trimmed."""
+    m, stream, sync = served
+    _engines_at(m, stream, sync, rows, seed=60 + rows)
+
+
+def test_engines_agree_at_uneven_row_counts_n_group(tmp_path):
+    """The same for an N-group model, whose extra groups' blocks are
+    padded too."""
+    m = Model(Solver(batch_size=16, lr=1e-2),
+              DataReaderParams(num_dense_features=4), name="ngroup")
+    m.add(Input(dense_dim=4))
+    m.add(SparseEmbedding(vocab_sizes=[300, 100], dim=8, top_name="a"))
+    m.add(SparseEmbedding(vocab_sizes=[60], dim=4, top_name="b"))
+    m.add(SparseEmbedding(vocab_sizes=[40, 20, 10], dim=2, top_name="c"))
+    m.add(DenseLayer("concat", ["dense", "a", "b", "c"], ["flat"]))
+    m.add(DenseLayer("mlp", ["flat"], ["logit"], units=(16, 1)))
+    m.add(DenseLayer("sigmoid", ["logit"], ["prob"]))
+    m.compile()
+    m.fit(SyntheticCTR(m.cfg, 16).batch, steps=2)
+    stream = m.deploy(str(tmp_path / "dep"), cache_capacity=256,
+                      max_batch=8)
+    assert stream.extra_hps
+    sync = InferenceServer(m.model, m.dense_params(), stream.hps,
+                           extra_hps=stream.extra_hps,
+                           hotness=stream.hotness, max_batch=8,
+                           engine="sync")
+    for rows in (3, 6):
+        _engines_at(m, stream, sync, rows, seed=80 + rows)
 
 
 def test_stream_burst_error_reaches_every_handle(served):
