@@ -219,19 +219,29 @@ class EmbeddingCollection:
         already inside a shard_map over the full mesh (the manual train
         step); ``params``/``ids`` are then per-device blocks.
         """
+        return self.lookup_with_stats(params, ids, manual=manual)[0]
+
+    def lookup_with_stats(self, params: Dict[str, jax.Array],
+                          ids: jax.Array, *, manual: bool = False
+                          ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """:meth:`lookup` and the exchange's counters
+        (:func:`strategies.exchange_stats`), summed over every device and
+        group: with the batch replicated over ``model``, an id counts once
+        per device of that axis. Groups with no exchange add nothing."""
         if manual:
             return self._lookup_shard(params, ids)
         fn = compat.shard_map(
             functools.partial(self._lookup_shard),
             mesh=self.mesh,
             in_specs=(self.param_specs(), P(self.dp_axes, None, None)),
-            out_specs=P(self.dp_axes, None, None),
+            out_specs=(P(self.dp_axes, None, None), P()),
             check_vma=False,
         )
         return fn(params, ids)
 
     def _lookup_shard(self, params, ids):
         outs = []
+        stats = strategies.exchange_stats()
         cd = self.compute_dtype
         if "dp" in self.groups:
             g = self.groups["dp"]
@@ -240,15 +250,19 @@ class EmbeddingCollection:
         if "dist" in self.groups:
             g = self.groups["dist"]
             rows = global_row_ids(ids[:, np.asarray(g.table_indices), :], g)
-            outs.append(self._dist_lookup(params["dist"], rows, g))
+            pooled, s = self._dist_lookup(params["dist"], rows, g)
+            outs.append(pooled)
+            stats = strategies.merge_stats(stats, s)
         if "loc" in self.groups:
             g = self.groups["loc"]
-            outs.append(strategies.localized(
+            pooled, s = strategies.localized(
                 params["loc"], ids[:, np.asarray(g.table_indices), :],
                 dp_axes=self.dp_axes, all_axes=self.all_axes,
                 model_axis=self.model_axis,
                 tables_per_shard=g.num_tables // self.n_devices,
-                compute_dtype=cd))
+                compute_dtype=cd)
+            outs.append(pooled)
+            stats = strategies.merge_stats(stats, s)
         if "hot" in self.groups:
             gh, gc = self.groups["hot"], self.groups["cold"]
             tids = ids[:, np.asarray(gh.table_indices), :]
@@ -260,8 +274,9 @@ class EmbeddingCollection:
             hot_rows = jnp.where(is_hot, tids + hot_off, -1)
             cold_rows = jnp.where(is_cold, tids - hot_n + cold_off, -1)
             pooled = self._pool(params["hot"], hot_rows, compute_dtype=cd)
-            pooled = pooled + self._dist_lookup(params["cold"], cold_rows, gc)
-            outs.append(pooled)
+            cold, s = self._dist_lookup(params["cold"], cold_rows, gc)
+            outs.append(pooled + cold)
+            stats = strategies.merge_stats(stats, s)
         out = jnp.concatenate(outs, axis=1)[:, self._inv_perm, :]
         # mean combiner renorm (per original table)
         mean_mask = np.asarray(
@@ -270,7 +285,7 @@ class EmbeddingCollection:
             denom = combiner_mask_denom(ids).astype(out.dtype)
             out = jnp.where(jnp.asarray(mean_mask)[None, :, None],
                             out / denom, out)
-        return out
+        return out, strategies.psum_stats(stats, self.all_axes)
 
     def _dist_lookup(self, mega, rows, g: TableGroup):
         rpad = self._padded_rows(g)

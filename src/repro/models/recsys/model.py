@@ -13,7 +13,7 @@ batch = {"dense": [B, Nd] f32, "cat": [B, T, H] int32 (-1 pad), "label": [B]}
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import RecsysConfig, EmbeddingTableConfig
 from repro.core.embedding import EmbeddingCollection, resolve_strategies
+from repro.core.embedding.strategies import merge_stats
 from repro.launch.mesh import mesh_config_for
 from repro.models.recsys import dense_graph, layers
 from repro.kernels import ops as kops
@@ -285,27 +286,36 @@ class RecsysModel:
 
     def apply(self, params: Dict, batch: Dict, *,
               manual: bool = False) -> jax.Array:
+        return self.apply_with_stats(params, batch, manual=manual)[0]
+
+    def apply_with_stats(self, params: Dict, batch: Dict, *,
+                         manual: bool = False
+                         ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """:meth:`apply` and the embedding exchange's counters, summed over
+        every collection (``EmbeddingCollection.lookup_with_stats``)."""
         cat = batch["cat"]
         # single-group models keep the whole-cat trace they always had;
         # N-group models slice each collection's column span
         cat_p = cat if not self.extra \
             else cat[:, slice(*self._group_cols["embedding"]), :]
-        emb = self.embedding.lookup(params["embedding"], cat_p,
-                                    manual=manual)
+        emb, stats = self.embedding.lookup_with_stats(
+            params["embedding"], cat_p, manual=manual)
         wide = None
         if self.wide is not None:
-            wide = self.wide.lookup(params["wide_embedding"], cat_p,
-                                    manual=manual)       # [B, T, 1]
+            wide, s = self.wide.lookup_with_stats(       # [B, T, 1]
+                params["wide_embedding"], cat_p, manual=manual)
+            stats = merge_stats(stats, s)
         extras = None
         if self.extra:
             extras = {}
             for name, coll in self.extra.items():
                 key = f"embedding@{name}"
-                s = slice(*self._group_cols[key])
-                extras[name] = coll.lookup(params[key], cat[:, s, :],
-                                           manual=manual)
+                sl = slice(*self._group_cols[key])
+                extras[name], s = coll.lookup_with_stats(
+                    params[key], cat[:, sl, :], manual=manual)
+                stats = merge_stats(stats, s)
         return self.apply_dense(params, batch["dense"], emb, wide,
-                                extras=extras)
+                                extras=extras), stats
 
     def apply_dense(self, params: Dict, dense: jax.Array, emb: jax.Array,
                     wide: Optional[jax.Array] = None, *,
@@ -377,5 +387,12 @@ class RecsysModel:
 
     def loss_fn(self, params: Dict, batch: Dict, *,
                 manual: bool = False) -> jax.Array:
-        logits = self.apply(params, batch, manual=manual)
-        return layers.bce_with_logits(logits, batch["label"])
+        return self.loss_and_stats(params, batch, manual=manual)[0]
+
+    def loss_and_stats(self, params: Dict, batch: Dict, *,
+                       manual: bool = False
+                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """The loss and the exchange's counters (:meth:`apply_with_stats`),
+        for ``jax.value_and_grad(..., has_aux=True)``."""
+        logits, stats = self.apply_with_stats(params, batch, manual=manual)
+        return layers.bce_with_logits(logits, batch["label"]), stats

@@ -49,6 +49,30 @@ Training (``train/trainer.py``); ``step`` is the trainer's step number:
   train.sync             the wait for the step's loss
   train.checkpoint       handing a checkpoint to the async saver
 
+Device scopes (``jax.named_scope``: not host spans, but the op metadata
+of the device trace's ``XLA Ops``, backward ops included):
+
+  mp.exchange            an embedding strategy's exchange
+                         (``core/embedding/strategies.py``): id bucketing,
+                         the all-to-alls or all-gather and reduce-scatter,
+                         the owner's gather
+  mp.sparse_update       the sparse optimizer's update of the tables
+                         (``train/train_step.py``)
+
+Counters (``Trainer.counters()``), combined since the trainer was built
+from each step's metrics (``core/embedding/strategies.py::exchange_stats``
+and its one rule, ``EXCHANGE_COUNTERS``), read with the loss at the step's
+sync; a step on one device returns none, and they stay at zero:
+
+  exchange_ids           non-padding ids an exchange routed to an owner,
+                         summed over the devices (an id replicated over
+                         ``model`` counts once per device of that axis)
+  exchange_dropped       those that overflowed their owner's all-to-all
+                         bucket and read a zero vector
+  exchange_peak_load     the fullest all-to-all bucket over the mean one,
+                         the largest of any step; ids drop once it passes
+                         the capacity factor
+
 Counters (``InferenceServer.counters()``), summed since the server was
 made or ``reset_serving_stats`` last ran:
 

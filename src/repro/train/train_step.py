@@ -12,6 +12,12 @@ Two distribution modes:
              "compressed parameter" idea applied to gradient traffic), and
              every embedding collective is the strategy's own.
 
+Both return ``(params, opt_state, metrics)``: ``metrics`` holds ``loss``,
+``grad_norm`` and, on a mesh of more than one device, the embedding
+exchange's counters (``core/embedding/strategies.py::EXCHANGE_COUNTERS``),
+summed over the devices. The sparse optimizer's update runs under
+``jax.named_scope("mp.sparse_update")``.
+
 Loss-scaling convention for manual mode (see the derivation in this file's
 history / DESIGN.md §4): each device contributes ``local_mean / N_devices``;
 MP-sharded embedding grads are then correct *without* any psum (the
@@ -31,6 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import compat
 
 from repro.configs.base import TrainConfig
+from repro.core.embedding.strategies import exchange_stats, merge_stats
 from repro.optim import optimizers as dense_opt_lib
 from repro.optim.sparse import make_sparse
 from repro.optim.optimizers import clip_by_global_norm
@@ -62,10 +69,23 @@ def _apply_updates(params, grads, opt_state, dense_opt, sparse_opt, tcfg):
     dense_g, gnorm = clip_by_global_norm(dense_g, tcfg.grad_clip)
     new_dense, dstate = dense_opt.update(dense_g, opt_state["dense"],
                                          dense_p)
-    new_sparse, sstate = sparse_opt.update(sparse_g, opt_state["sparse"],
-                                           sparse_p)
+    with jax.named_scope("mp.sparse_update"):
+        new_sparse, sstate = sparse_opt.update(sparse_g,
+                                               opt_state["sparse"], sparse_p)
     new_params = {**new_dense, **new_sparse}
     return new_params, {"dense": dstate, "sparse": sstate}, gnorm
+
+
+def _metrics(model, loss, gnorm, stats) -> Dict[str, jax.Array]:
+    """The step's metrics: the loss, the gradient norm and, where the mesh
+    spans devices, the exchange's counters. On one device nothing crosses
+    between devices and the step returns none: three more outputs moved
+    the one-chip step's buffers, and its table-gradient scatter then took
+    2 ms a step longer on a v5e chip (dcn-criteo, batch 16384)."""
+    out = {"loss": loss, "grad_norm": gnorm}
+    if model.mesh.devices.size > 1:
+        out.update(stats)
+    return out
 
 
 def init_opt_state(params: Dict, tcfg: TrainConfig) -> Dict:
@@ -82,21 +102,16 @@ def init_opt_state(params: Dict, tcfg: TrainConfig) -> Dict:
 def build_train_step(model, tcfg: TrainConfig) -> Callable:
     dense_opt, sparse_opt = build_optimizers(tcfg)
 
-    def loss_fn(params, batch):
-        if tcfg.microbatches <= 1:
-            return model.loss_fn(params, batch)
-        # gradient accumulation happens in grad-land below
-        return model.loss_fn(params, batch)
-
     def train_step(params, opt_state, batch):
         if tcfg.microbatches > 1:
-            loss, grads = _accumulated_grads(model, params, batch,
-                                             tcfg.microbatches)
+            loss, grads, stats = _accumulated_grads(model, params, batch,
+                                                    tcfg.microbatches)
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            (loss, stats), grads = jax.value_and_grad(
+                model.loss_and_stats, has_aux=True)(params, batch)
         new_params, new_state, gnorm = _apply_updates(
             params, grads, opt_state, dense_opt, sparse_opt, tcfg)
-        return new_params, new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_params, new_state, _metrics(model, loss, gnorm, stats)
 
     return train_step
 
@@ -108,18 +123,20 @@ def _accumulated_grads(model, params, batch, k: int):
     def one(i):
         sl = lambda x: jax.lax.dynamic_slice_in_dim(x, i * mb, mb, axis=0)
         micro = {kk: sl(v) for kk, v in batch.items()}
-        return jax.value_and_grad(model.loss_fn)(params, micro)
+        return jax.value_and_grad(model.loss_and_stats, has_aux=True)(
+            params, micro)
 
     def body(carry, i):
-        loss_acc, grad_acc = carry
-        loss, grads = one(i)
+        loss_acc, grad_acc, stats_acc = carry
+        (loss, stats), grads = one(i)
         grad_acc = jax.tree.map(lambda a, g: a + g / k, grad_acc, grads)
-        return (loss_acc + loss / k, grad_acc), ()
+        return (loss_acc + loss / k, grad_acc,
+                merge_stats(stats_acc, stats)), ()
 
     zero_g = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    (loss, grads), _ = jax.lax.scan(body, (jnp.zeros(()), zero_g),
-                                    jnp.arange(k))
-    return loss, grads
+    (loss, grads, stats), _ = jax.lax.scan(
+        body, (jnp.zeros(()), zero_g, exchange_stats()), jnp.arange(k))
+    return loss, grads, stats
 
 
 def jit_train_step(model, tcfg: TrainConfig, mesh):
@@ -184,9 +201,11 @@ def build_manual_train_step(model, tcfg: TrainConfig, mesh) -> Callable:
         # per-device loss scaled so that summing over every device gives
         # the global-mean loss (see module docstring)
         def scaled_loss(p):
-            return model.loss_fn(p, batch, manual=True) / n_dev
+            loss, stats = model.loss_and_stats(p, batch, manual=True)
+            return loss / n_dev, stats
 
-        loss, grads = jax.value_and_grad(scaled_loss)(params)
+        (loss, stats), grads = jax.value_and_grad(scaled_loss,
+                                                  has_aux=True)(params)
         # replicated params: explicit (optionally compressed) all-reduce;
         # MP-sharded embedding tables are already correct.
         def fix(path_key, g, spec):
@@ -200,21 +219,20 @@ def build_manual_train_step(model, tcfg: TrainConfig, mesh) -> Callable:
             lambda g, s: fix(None, g, s), grads, specs,
             is_leaf=lambda x: isinstance(x, P))
         loss = jax.lax.psum(loss, all_axes)
-        return loss, grads
+        return loss, grads, stats       # stats: already summed (lookup)
 
     def train_step(params, opt_state, batch):
         specs = param_specs(params)
-        from repro.data.pipeline import batch_shardings  # specs only
         b_spec = {"dense": P(dp_axes, None), "cat": P(dp_axes, None, None),
                   "label": P(dp_axes)}
-        loss, grads = compat.shard_map(
+        loss, grads, stats = compat.shard_map(
             grad_shard_fn, mesh=mesh,
             in_specs=(specs, b_spec),
-            out_specs=(P(), specs),
+            out_specs=(P(), specs, P()),
             check_vma=False,
         )(params, batch)
         new_params, new_state, gnorm = _apply_updates(
             params, grads, opt_state, dense_opt, sparse_opt, tcfg)
-        return new_params, new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_params, new_state, _metrics(model, loss, gnorm, stats)
 
     return train_step

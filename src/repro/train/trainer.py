@@ -11,6 +11,9 @@ Production behaviours implemented (and unit-tested):
     bookkeeping + hook).
   * elastic scaling — checkpoints store logical (mesh-independent) arrays;
     ``Trainer.restore`` re-imports them for whatever mesh it runs on.
+
+``counters()`` combines the embedding exchange's counters over the steps
+since the trainer was built, read with the loss at each step's sync.
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ import numpy as np
 
 from repro import tracing
 from repro.configs.base import TrainConfig
+from repro.core.embedding.strategies import EXCHANGE_COUNTERS, merge_stats
 from repro.data.pipeline import put_batch
 from repro.train import checkpoint as ckpt_lib
 from repro.train.train_step import (
@@ -45,6 +49,8 @@ class Trainer:
         self.straggler_factor = straggler_factor
         self.step_times: List[float] = []
         self.stragglers = 0
+        self._counters: Dict[str, float] = dict.fromkeys(EXCHANGE_COUNTERS,
+                                                         0)
         n_dev = int(np.prod(mesh.devices.shape))
         #: model-parallel placement: on a real multi-device mesh the
         #: params live in their per-strategy shardings and the step is
@@ -151,7 +157,15 @@ class Trainer:
                         params, opt_state, metrics = self._step(
                             params, opt_state, batch)
                     with tracing.span("train.sync", step=step):
-                        loss = float(metrics["loss"])
+                        loss, stats = jax.device_get(
+                            (metrics["loss"],
+                             {k: metrics[k] for k in EXCHANGE_COUNTERS
+                              if k in metrics}))
+                    loss = float(loss)
+                    if stats:
+                        self._counters = merge_stats(
+                            self._counters,
+                            {k: v.item() for k, v in stats.items()}, max)
                     dt = time.perf_counter() - t0
                     self._watch_stragglers(dt)
                     history.append({"step": step, "loss": loss, "time": dt})
@@ -177,6 +191,13 @@ class Trainer:
             self.saver.wait()
         return {"params": params, "opt_state": opt_state,
                 "history": history, "stragglers": self.stragglers}
+
+    def counters(self) -> Dict[str, float]:
+        """The embedding exchange's counters since the trainer was built:
+        ``exchange_ids`` and ``exchange_dropped`` summed over the steps,
+        ``exchange_peak_load`` the largest of any step; zeros on one
+        device, where the step returns none."""
+        return dict(self._counters)
 
     def _watch_stragglers(self, dt: float):
         if len(self.step_times) >= 5:

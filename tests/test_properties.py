@@ -73,11 +73,12 @@ def test_lookup_hotness_permutation_invariant(seed):
 def test_bucket_by_owner_invariants(m, n_shards, capacity, seed):
     rng = np.random.default_rng(seed)
     flat = jnp.asarray(rng.integers(-1, n_shards * 13, m), jnp.int32)
-    send, slot_of, valid = jax.jit(
+    send, slot_of, valid, fill = jax.jit(
         _bucket_by_owner, static_argnums=(1, 2))(flat, n_shards, capacity)
     send = np.asarray(send)
     slot_of = np.asarray(slot_of)
     valid = np.asarray(valid)
+    fill = np.asarray(fill)
     flat = np.asarray(flat)
 
     # 1. every valid id landed in its owner's bucket at the slot recorded
@@ -95,6 +96,13 @@ def test_bucket_by_owner_invariants(m, n_shards, capacity, seed):
     for i in range(m):
         if flat[i] >= 0 and not valid[i]:
             assert (send[flat[i] % n_shards] >= 0).sum() == capacity
+    # 5. fill counts each owner's ids before the cut; what the cut drops
+    #    is each owner's excess over capacity
+    owners = flat[flat >= 0] % n_shards
+    np.testing.assert_array_equal(fill, np.bincount(owners,
+                                                    minlength=n_shards))
+    dropped = int(((flat >= 0) & ~valid).sum())
+    assert dropped == int(np.maximum(fill - capacity, 0).sum())
 
 
 # ---------------------------------------------------------------------------
