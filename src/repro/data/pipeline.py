@@ -1,61 +1,16 @@
-"""Host input pipeline: background prefetch + sharding-aware device_put.
-
-HugeCTR overlaps its data reader with compute via CUDA streams; the JAX
-analogue is a daemon thread filling a bounded queue while the device works,
-plus ``jax.device_put`` with the batch's NamedSharding so each host only
-materializes its addressable shards.
+"""Host batches onto the device: ``batch_shardings`` splits a batch's rows
+over the mesh's data-parallel axes, and ``put_batch`` is ``jax.device_put``
+in those shardings, so each host materializes only its addressable shards.
+The trainer overlaps the next batch's build and put with the running step
+(``train/trainer.py``).
 """
 from __future__ import annotations
 
-import queue
-import threading
-from typing import Callable, Dict, Iterator, Optional
+from typing import Dict
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-class Prefetcher:
-
-    def __init__(self, source: Iterator, depth: int = 2,
-                 transform: Optional[Callable] = None):
-        self._source = source
-        self._transform = transform
-        self._q: queue.Queue = queue.Queue(maxsize=depth)
-        self._err: Optional[BaseException] = None
-        self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._run, daemon=True)
-        self._thread.start()
-
-    def _run(self):
-        try:
-            for item in self._source:
-                if self._stop.is_set():
-                    return
-                if self._transform is not None:
-                    item = self._transform(item)
-                self._q.put(item)
-        except BaseException as e:  # surfaced on next()
-            self._err = e
-        finally:
-            self._q.put(None)
-
-    def __iter__(self):
-        return self
-
-    def __next__(self):
-        item = self._q.get()
-        if item is None:
-            if self._err is not None:
-                raise self._err
-            raise StopIteration
-        return item
-
-    def close(self):
-        self._stop.set()
-        while not self._q.empty():
-            self._q.get_nowait()
 
 
 def batch_shardings(mesh: Mesh, dp_axes=None) -> Dict[str, NamedSharding]:

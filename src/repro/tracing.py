@@ -40,13 +40,18 @@ name, ``rows`` a row count:
                          for callers that want ``b`` rows, the ``[:b]``
                          slice (``rows``: the group's rows)
 
-Training (``train/trainer.py``); ``step`` is the trainer's step number:
+Training (``train/trainer.py``); ``step`` is the trainer's step number,
+on ``train.data`` and ``train.put_batch`` the step of the batch they build:
 
   train.step             one loop iteration, holding the five below
-  train.data             the host batch, ``data_fn(step)``
-  train.put_batch        the host-to-device transfer
   train.dispatch         the train step's dispatch
-  train.sync             the wait for the step's loss
+  train.data             the host batch, ``data_fn(step)``: after the
+                         dispatch, the next step's batch, built while the
+                         device runs this one; before it only where no
+                         batch was built ahead (a call's first step)
+  train.put_batch        that batch's host-to-device transfer
+  train.sync             the wait for the step's loss, after the next
+                         batch is built
   train.checkpoint       handing a checkpoint to the async saver
 
 Device scopes (``jax.named_scope``: not host spans, but the op metadata
@@ -72,6 +77,14 @@ sync; a step on one device returns none, and they stay at zero:
   exchange_peak_load     the fullest all-to-all bucket over the mean one,
                          the largest of any step; ids drop once it passes
                          the capacity factor
+
+Counters (``Trainer.input_counters()``), steps dispatched since the
+trainer was built, kept apart from ``counters()``:
+
+  batches_ahead          steps whose batch was built while the step
+                         before ran
+  batches_in_line        steps whose batch was built with no step in
+                         flight: a clean call of n steps adds n - 1 and 1
 
 Counters (``InferenceServer.counters()``), summed since the server was
 made or ``reset_serving_stats`` last ran:

@@ -12,8 +12,15 @@ Production behaviours implemented (and unit-tested):
   * elastic scaling — checkpoints store logical (mesh-independent) arrays;
     ``Trainer.restore`` re-imports them for whatever mesh it runs on.
 
+Input lookahead: each step dispatches on a batch already on the device,
+then builds and puts the next step's batch while the device runs, then
+waits for its loss. Only the first step of a call (or of a replay that
+moves off the held batch) builds its batch with nothing in flight; no
+batch is built for a step the call does not run.
+
 ``counters()`` combines the embedding exchange's counters over the steps
-since the trainer was built, read with the loss at each step's sync.
+since the trainer was built, read with the loss at each step's sync;
+``input_counters()`` counts the steps whose batch was built ahead.
 """
 from __future__ import annotations
 
@@ -51,6 +58,7 @@ class Trainer:
         self.stragglers = 0
         self._counters: Dict[str, float] = dict.fromkeys(EXCHANGE_COUNTERS,
                                                          0)
+        self._input = {"batches_ahead": 0, "batches_in_line": 0}
         n_dev = int(np.prod(mesh.devices.shape))
         #: model-parallel placement: on a real multi-device mesh the
         #: params live in their per-strategy shardings and the step is
@@ -143,19 +151,24 @@ class Trainer:
             start += 1
         history = []
         step = start
+        ahead = None        # (step, device batch) built while a step ran
         while step < num_steps:
             try:
                 with tracing.span("train.step", step=step):
                     if self.failure_injector is not None:
                         self.failure_injector(step)
                     t0 = time.perf_counter()
-                    with tracing.span("train.data", step=step):
-                        host = self.data_fn(step)
-                    with tracing.span("train.put_batch", step=step):
-                        batch = put_batch(host, self.mesh)
+                    if ahead is not None and ahead[0] == step:
+                        batch, key = ahead[1], "batches_ahead"
+                    else:
+                        batch, key = self._build(step), "batches_in_line"
                     with tracing.span("train.dispatch", step=step):
                         params, opt_state, metrics = self._step(
                             params, opt_state, batch)
+                    self._input[key] += 1
+                    # the next step's batch, while this one runs
+                    if step + 1 < num_steps:
+                        ahead = (step + 1, self._build(step + 1))
                     with tracing.span("train.sync", step=step):
                         loss, stats = jax.device_get(
                             (metrics["loss"],
@@ -191,6 +204,20 @@ class Trainer:
             self.saver.wait()
         return {"params": params, "opt_state": opt_state,
                 "history": history, "stragglers": self.stragglers}
+
+    def _build(self, step: int):
+        """``data_fn(step)``, put on the device in the batch's shardings."""
+        with tracing.span("train.data", step=step):
+            host = self.data_fn(step)
+        with tracing.span("train.put_batch", step=step):
+            return put_batch(host, self.mesh)
+
+    def input_counters(self) -> Dict[str, int]:
+        """Steps dispatched since the trainer was built: ``batches_ahead``
+        on a batch built while the step before ran, ``batches_in_line``
+        on one built with no step in flight (each call's first step, and
+        the first after a restore that moves off the held batch)."""
+        return dict(self._input)
 
     def counters(self) -> Dict[str, float]:
         """The embedding exchange's counters since the trainer was built:
