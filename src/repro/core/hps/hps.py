@@ -18,8 +18,7 @@ two stages are double-buffered on a dedicated host worker so table
 (query *i+1*'s probes run while the host blocks materializing query *i*'s
 result — the serving-loop shape; ``materialize=False`` hands the caller
 un-synced device arrays so the serve loop can chain the dense net before
-any host sync). ``lookup_stage_sync`` is the no-overlap reference engine
-the benchmarks compare against. Pooling honors each table's combiner
+any host sync). Pooling honors each table's combiner
 (sum or mean); the ``hotness`` argument selects the valid id columns per
 table (and is validated against the query shape instead of being silently
 ignored).
@@ -264,7 +263,7 @@ class HPS:
                       overflow: List[Tuple[int, np.ndarray, np.ndarray,
                                            int]]) -> jax.Array:
         """Run table ``ti``'s device stage and record its outputs — the
-        per-plan bookkeeping shared by every engine variant."""
+        per-plan bookkeeping shared by every lookup driver."""
         with tracing.span("hps.device_stage", table=self.tables[ti].name):
             sb, payload = self._device_stage(ti, plan, b, bp,
                                              blocks[ti].shape[1])
@@ -377,36 +376,6 @@ class HPS:
 
         return self._finalize(payloads, slot_blocks, blocks, overflow, b,
                               padded)
-
-    def lookup_stage_sync(self, cat: np.ndarray,
-                          hotness: Optional[List[int]] = None, *,
-                          padded: bool = False) -> jax.Array:
-        """Fully stage-synchronous lookup: BLOCK on each table's device
-        scatter before the next host probe, and block on the pooled
-        stack before returning — zero overlap of any kind, not even
-        XLA's async dispatch. The no-overlap reference engine the
-        pipelining benchmarks (and the ``stage_sync`` server engine)
-        compare against; bit-identical outputs to :meth:`lookup`, whose
-        ``padded`` it takes."""
-        cat = np.asarray(cat)
-        blocks = self._split_query(cat, hotness)
-        self._check_dims()
-        b = cat.shape[0]
-        if b == 0:
-            return jnp.zeros((0, len(self.tables), self.tables[0].dim),
-                             jnp.float32)
-        bp = bucket_rows(b)
-        slot_blocks: List[jax.Array] = []
-        payloads: List[jax.Array] = []
-        overflow: List[Tuple[int, np.ndarray, np.ndarray, int]] = []
-        for ti in range(len(self.tables)):
-            payload = self._collect_plan(ti, self._probe(ti, blocks), b,
-                                         bp, blocks, slot_blocks,
-                                         payloads, overflow)
-            jax.block_until_ready(payload)             # no overlap
-        return jax.block_until_ready(
-            self._finalize(payloads, slot_blocks, blocks, overflow, b,
-                           padded))
 
     def _timed_probe(self, ti: int, blocks: List[np.ndarray],
                      rec: List[float]) -> LookupPlan:

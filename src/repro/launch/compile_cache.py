@@ -1,8 +1,8 @@
 """Where the entry points keep JAX's persistent compilation cache.
 
 Called from the launchers' ``main`` (and ``chip_smoke.py``,
-``benchmarks.run``), never on import and never in tests: a library user
-keeps control of their own JAX config.
+``benchmarks/chip/run.py``), never on import and never in tests: a
+library user keeps control of their own JAX config.
 """
 from __future__ import annotations
 

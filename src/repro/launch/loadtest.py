@@ -17,8 +17,7 @@ rate past capacity so the admission controller's shedding is visible.
 The per-phase, per-model picture — client-observed p50/p99/p999,
 delivered-qps series, shed / SLO-violation / expiry counts from BOTH
 sides (driver-observed and server counters) — persists to
-``artifacts/loadtest.json`` (re-surfaced into
-``artifacts/bench_results.csv`` by ``benchmarks/roofline_report.py``).
+``artifacts/loadtest.json``.
 
   # demo: train 2 recipes briefly, deploy an ensemble bundle, load-test it
   PYTHONPATH=src python -m repro.launch.loadtest \

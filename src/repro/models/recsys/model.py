@@ -139,8 +139,7 @@ class RecsysModel:
                  comm: str = "allgather_rs",
                  a2a_threshold: int = 65536,
                  embed_shard_axes: str = "all",
-                 use_kernels: bool = False,
-                 dense_executor: str = "graph"):
+                 use_kernels: bool = False):
         self.cfg = cfg
         self.mesh = mesh
         if cfg.model == "dlrm" and cfg.bottom_mlp[-1] != cfg.embedding_dim:
@@ -148,15 +147,6 @@ class RecsysModel:
                 "DLRM needs bottom_mlp[-1] == embedding_dim for the "
                 f"interaction, got {cfg.bottom_mlp[-1]} != "
                 f"{cfg.embedding_dim}")
-        if dense_executor not in ("graph", "reference"):
-            raise ValueError(
-                f"dense_executor must be 'graph' (the compiled program) "
-                f"or 'reference' (the fixed pipeline), got "
-                f"{dense_executor!r}")
-        if dense_executor == "reference" and cfg.model == "graph":
-            raise ValueError(
-                "the reference executor only covers the four canonical "
-                "recipes; model='graph' always runs the compiled program")
         mesh_cfg = mesh_config_for(mesh)
         tables = resolve_strategies(cfg.tables, mesh_cfg, global_batch)
         cd = jnp.bfloat16 if cfg.dtype == "bf16" else jnp.float32
@@ -176,7 +166,6 @@ class RecsysModel:
             shard_axes=embed_shard_axes, pool_fn=pool)
         self.compute_dtype = cd
         self.use_kernels = use_kernels
-        self.dense_executor = dense_executor
         #: the compiled dense program — ONE executor for every model
         #: kind: canonical recipes bind their historical params,
         #: model="graph" compiles the embedded DAG
@@ -329,11 +318,8 @@ class RecsysModel:
         Execution is the compiled :class:`DenseGraphProgram` — the same
         node loop for the canonical recipes and for novel graphs
         (bit-exact with the historical fixed pipeline, which survives as
-        :meth:`apply_dense_reference` for the parity tests and the
-        compile-overhead benchmark).
+        :meth:`apply_dense_reference` for the parity tests).
         """
-        if self.dense_executor == "reference":
-            return self.apply_dense_reference(params, dense, emb, wide)
         env = self.program.make_env(dense, emb, wide, self.compute_dtype,
                                     extras=extras)
         return self.program.apply(params, env, self.compute_dtype)

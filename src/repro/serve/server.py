@@ -6,25 +6,20 @@ cache -> L2 VDB -> L3 PDB), and the jitted dense net computes predictions.
 ``deploy_from_training`` exports a trained model into the PDB — the
 offline-training deployment path; online updates arrive via the bus.
 
-The serve loop is a STREAM-FED pipeline (``engine="stream"``, the
-default): drained request groups feed the dense network directly from
-``HPS.lookup_stream`` with no caller-thread materialization in between —
-while query *i-1*'s prediction materializes, query *i*'s pooled
-embeddings and dense net are computing on device and query *i+1*'s index
-probes (and their remote L2/L3 miss fetches) run on the HPS host
-workers. The only host sync point per query is the prediction itself.
-Predictions are bit-identical to the unpipelined path: the per-plan
-payload snapshots make the lookup machinery order-independent, and the
-dense net is the same jitted function either way. Every engine runs a
-group at its power-of-two row bucket (``hps.bucket_rows``, the pooled
-gather's) from pooled block to probability, so the dense net compiles
-once per bucket, not once per group; the host keeps the group's own rows
-after the sync. Two reference engines
-remain selectable: ``"sync"`` (drain -> one blocking ``predict`` per
-group — the old loop, where XLA async dispatch still overlaps device
-work behind the host) and ``"stage_sync"`` (every device stage blocked
-before the next host stage — the no-overlap baseline the benchmarks
-measure against).
+The serve loop is one STREAM-FED pipeline: drained request groups feed
+the dense network directly from ``HPS.lookup_stream`` with no
+caller-thread materialization in between — while query *i-1*'s
+prediction materializes, query *i*'s pooled embeddings and dense net are
+computing on device and query *i+1*'s index probes (and their remote
+L2/L3 miss fetches) run on the HPS host workers. The only host sync
+point per query is the prediction itself. ``predict()`` is the
+synchronous entry point beside it (one lookup, one dense dispatch, one
+sync per call), and both give the same bits: the per-plan payload
+snapshots make the lookup machinery order-independent, and the dense net
+is the same jitted function either way. Both run a group at its
+power-of-two row bucket (``hps.bucket_rows``, the pooled gather's) from
+pooled block to probability, so the dense net compiles once per bucket,
+not once per group; the host keeps the group's own rows after the sync.
 
 ADMISSION CONTROL (the submit path's QoS layer, off by default so the
 bare server behaves exactly as before): a server constructed — or
@@ -86,8 +81,6 @@ from repro.core.hps.message_bus import MessageBus
 from repro.core.hps.persistent_db import PersistentDB
 from repro.core.hps.volatile_db import VolatileDB
 from repro.loadgen.metrics import LatencyHistogram
-
-ENGINES = ("stream", "sync", "stage_sync")
 
 
 class ServerOverloaded(Exception):
@@ -180,13 +173,9 @@ class InferenceServer:
                  hotness: Optional[Sequence[int]] = None,
                  refresh_budget: int = 512,
                  refresh_poll_s: Optional[float] = None,
-                 engine: str = "stream",
                  queue_depth: Optional[int] = None,
                  slo_ms: Optional[float] = None,
                  deadline_batching: bool = True):
-        if engine not in ENGINES:
-            raise ValueError(f"engine must be one of {ENGINES}, "
-                             f"got {engine!r}")
         self.model = model
         self.hps = hps
         self.wide_hps = wide_hps
@@ -205,7 +194,6 @@ class InferenceServer:
         self.hotness = list(hotness) if hotness is not None else None
         self.dense_params = dense_params
         self.max_batch = max_batch
-        self.engine = engine
         #: rows re-pulled per refresh chunk between drained batches
         self.refresh_budget = refresh_budget
         #: period of the full-mark sweep (None = only bus-marked rows)
@@ -322,8 +310,9 @@ class InferenceServer:
                        wide: Optional[jax.Array],
                        extras: Optional[Dict[str, jax.Array]] = None,
                        group: int = -1, *, rows: int) -> jax.Array:
-        """The one jitted dense-net-and-sigmoid dispatch — shared by every
-        engine so outputs are bit-identical across them.
+        """The one jitted dense-net-and-sigmoid dispatch — shared by
+        ``predict`` and the stream pipeline, so their outputs are
+        bit-identical.
 
         ``emb`` (and ``wide``, ``extras``) arrive at the lookup's bucket
         ``bucket_rows(rows)``; ``dense`` is padded to it here, on the
@@ -372,20 +361,6 @@ class InferenceServer:
                 c, h, pipelined=len(hps.tables) > 1, padded=True))
         out = self._dense_forward(dense, emb, wide, extras,
                                   rows=cat.shape[0])
-        out = np.asarray(out)[:cat.shape[0]]
-        self._record_latency(t0, rows=len(out))
-        return out
-
-    def _predict_stage_sync(self, dense: np.ndarray,
-                            cat: np.ndarray) -> np.ndarray:
-        """The no-overlap reference: every embedding device stage blocks
-        before the next host stage, and the dense net blocks before its
-        answer is read — nothing is left to XLA's async dispatch."""
-        t0 = time.perf_counter()
-        emb, wide, extras = self._lookups(
-            cat, lambda hps, c, h: hps.lookup_stage_sync(c, h, padded=True))
-        out = jax.block_until_ready(self._dense_forward(
-            dense, emb, wide, extras, rows=cat.shape[0]))
         out = np.asarray(out)[:cat.shape[0]]
         self._record_latency(t0, rows=len(out))
         return out
@@ -464,7 +439,7 @@ class InferenceServer:
 
     def _expired(self, req: _Req) -> bool:
         """Deadline shedding applies only with an SLO declared AND
-        deadline batching on — the fixed-coalescing reference arm serves
+        deadline batching on — with fixed coalescing the server serves
         everything it admitted, however late."""
         if self.slo_ms is None or not self.deadline_batching:
             return False
@@ -559,7 +534,7 @@ class InferenceServer:
             except queue.Full:
                 pass
 
-    # -- the stream-fed pipeline (engine="stream") ----------------------------------
+    # -- the stream-fed pipeline ---------------------------------------------------
 
     def _serve_burst_stream(self, first) -> None:
         """Pipeline one burst of requests end-to-end: request groups are
@@ -671,24 +646,7 @@ class InferenceServer:
             except queue.Empty:
                 self._refresh_tick()     # idle: drain the refresh backlog
                 continue
-            if self.engine == "stream":
-                self._serve_burst_stream(first)
-                continue
-            group = self._coalesce(first)
-            if group is None:               # errors already delivered
-                self._refresh_tick()
-                continue
-            _, reqs, dense, cat = group
-            try:
-                if self.engine == "stage_sync":
-                    preds = self._predict_stage_sync(dense, cat)
-                else:
-                    preds = self.predict(dense, cat)
-            except Exception as exc:
-                self._deliver_error(reqs, exc)
-            else:
-                self._deliver(reqs, preds)
-            self._refresh_tick()         # interleave refresh with serving
+            self._serve_burst_stream(first)
 
     def start(self):
         with self._admit_lock:

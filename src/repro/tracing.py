@@ -28,7 +28,7 @@ HPS (``core/hps/hps.py``, ``payload_store.py``); ``table`` is the table's
 name, ``rows`` a row count:
 
   hps.probe              one table's host index probe, on an HPS host
-                         worker in the pipelined engines (``table``)
+                         worker in the pipelined lookups (``table``)
   hps.miss_fetch         the L2 query, L3 fetch and L2 promotion of one
                          table's misses (``table``)
   hps.device_stage       one table's deferred scatter flush and slot

@@ -353,7 +353,7 @@ def test_server_counters_thread_safe():
         def apply_dense(self, p, d, e, w):  # never called in this test
             raise AssertionError
 
-    s = InferenceServer(_NoModel(), {}, None, engine="sync")
+    s = InferenceServer(_NoModel(), {}, None)
     n_threads, per = 8, 200
 
     def writer():

@@ -2,9 +2,11 @@
 exists to pin: after warmup the stream serve engine performs exactly ONE
 device->host sync per delivered request group and ZERO recompiles — for
 a canonical recipe (dlrm) and a novel graph arch (twotower) — while the
-no-overlap ``stage_sync`` reference engine, by construction, syncs far
-more (the positive control proving the monitor actually measures)."""
+no-overlap reference (``serving_reference.predict_stage_sync``), by
+construction, syncs far more (the positive control proving the monitor
+actually measures)."""
 import ast
+import glob
 import importlib.util
 import os
 
@@ -17,6 +19,7 @@ from repro.analysis import HotPathMonitor, active_monitor
 from repro.api import Solver
 from repro.data.synthetic import SyntheticCTR
 from repro.serve.server import InferenceServer
+from serving_reference import predict_stage_sync
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -118,7 +121,6 @@ def served(request, tmp_path_factory):
     m = _build(request.param)
     dep = str(tmp_path_factory.mktemp("san_" + request.param))
     server = m.deploy(dep, cache_capacity=256, max_batch=8)
-    assert server.engine == "stream"
     return m, server
 
 
@@ -183,7 +185,7 @@ def test_admission_control_preserves_hotpath_contract(served):
     m, server = served
     ctl = InferenceServer(m.model, m.dense_params(), server.hps,
                           wide_hps=server.wide_hps, max_batch=8,
-                          engine="stream", queue_depth=64,
+                          queue_depth=64,
                           slo_ms=10_000.0, deadline_batching=True)
     rows, k = 8, 5
     ctl.start()
@@ -209,32 +211,35 @@ def test_admission_control_preserves_hotpath_contract(served):
 
 
 def test_stage_sync_reference_syncs_more(served):
-    """Positive control: the no-overlap engine blocks every device
+    """Positive control: the no-overlap reference blocks every device
     stage, so the monitor must see MANY more syncs than groups — proof
     the one-sync result above is measurement, not a dead monitor."""
     m, server = served
     ref = InferenceServer(m.model, m.dense_params(), server.hps,
-                          wide_hps=server.wide_hps, max_batch=8,
-                          engine="stage_sync")
+                          wide_hps=server.wide_hps, max_batch=8)
     k, rows = 3, 8
     d = SyntheticCTR(m.cfg, rows, seed=77)
-    ref._predict_stage_sync(d.batch(0)["dense"], d.batch(0)["cat"])
+    predict_stage_sync(ref, d.batch(0)["dense"], d.batch(0)["cat"])
     with HotPathMonitor("stage_sync") as mon:
         for i in range(1, k + 1):
-            ref._predict_stage_sync(d.batch(i)["dense"],
-                                    d.batch(i)["cat"])
+            predict_stage_sync(ref, d.batch(i)["dense"],
+                               d.batch(i)["cat"])
     assert mon.sync_count > k          # per-table blocks + final asarray
 
 
 def test_benchmark_arms_run_uninstrumented():
-    """The speedup benchmark's timed arms must not import the sanitizer:
-    monitoring overhead is opt-in and never taxes reported numbers."""
-    path = os.path.join(ROOT, "benchmarks", "hps_speedup.py")
-    with open(path) as f:
-        tree = ast.parse(f.read())
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Import):
-            assert not any(a.name.startswith("repro.analysis")
-                           for a in node.names)
-        elif isinstance(node, ast.ImportFrom):
-            assert not (node.module or "").startswith("repro.analysis")
+    """No module of the chip benchmark imports the sanitizer: monitoring
+    overhead is opt-in and never taxes reported numbers."""
+    paths = sorted(glob.glob(os.path.join(ROOT, "benchmarks", "chip", "**",
+                                          "*.py"), recursive=True))
+    assert paths
+    for path in paths:
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                assert not any(a.name.startswith("repro.analysis")
+                               for a in node.names), path
+            elif isinstance(node, ast.ImportFrom):
+                assert not (node.module or "").startswith(
+                    "repro.analysis"), path

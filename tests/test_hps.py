@@ -9,6 +9,7 @@ from repro.core.hps.hps import HPS
 from repro.core.hps.message_bus import Consumer, MessageBus, Producer
 from repro.core.hps.persistent_db import PersistentDB
 from repro.core.hps.volatile_db import VolatileDB
+from serving_reference import lookup_stage_sync
 
 
 def _pdb_with_table(tmp_path, model="m", table="t0", vocab=100, dim=4):
@@ -177,7 +178,7 @@ def test_padded_lookup_is_lookup_then_zero_rows(tmp_path, combiner,
                                                 capacity):
     """``padded=True`` hands back the bucket-shaped block: its first
     ``b`` rows are ``lookup()``'s, bit for bit, and the padded rows are
-    zero — through every engine, and through the overflow fix (a query
+    zero — through every lookup driver, and through the overflow fix (a query
     with more unique ids than the L1 can take)."""
     pdb = PersistentDB(str(tmp_path / "pdb"))
     rng = np.random.default_rng(7)
@@ -203,7 +204,7 @@ def test_padded_lookup_is_lookup_then_zero_rows(tmp_path, combiner,
     blocks = {
         "lookup": hps.lookup(cat, padded=True),
         "pipelined": hps.lookup(cat, pipelined=True, padded=True),
-        "stage_sync": hps.lookup_stage_sync(cat, padded=True),
+        "stage_sync": lookup_stage_sync(hps, cat),
         "stream": next(iter(hps.lookup_stream([cat], materialize=False,
                                               padded=True))),
     }

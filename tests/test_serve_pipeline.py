@@ -15,6 +15,7 @@ from repro.api import (DataReaderParams, DenseLayer, Input, Model, Solver,
                        SparseEmbedding)
 from repro.data.synthetic import SyntheticCTR
 from repro.serve.server import InferenceServer
+from serving_reference import predict_stage_sync
 
 
 def _build(arch):
@@ -31,16 +32,15 @@ def _build(arch):
 @pytest.fixture(scope="module", params=["dlrm-criteo", "wdl-criteo"])
 def served(request, tmp_path_factory):
     """One trained model + its deployed HPS, behind TWO servers over the
-    SAME storage: the stream-fed engine under test and the unpipelined
-    reference. Embedding values are identical at every storage level, so
-    any prediction difference is the pipeline's fault."""
+    SAME storage: the stream-fed engine under test and a second server
+    whose ``predict()`` is the unpipelined reference. Embedding values
+    are identical at every storage level, so any prediction difference
+    is the pipeline's fault."""
     m = _build(request.param)
     dep = str(tmp_path_factory.mktemp("dep_" + request.param))
     stream = m.deploy(dep, cache_capacity=256, max_batch=8)
-    assert stream.engine == "stream"            # the default engine
     sync = InferenceServer(m.model, m.dense_params(), stream.hps,
-                           wide_hps=stream.wide_hps, max_batch=8,
-                           engine="sync")
+                           wide_hps=stream.wide_hps, max_batch=8)
     return m, stream, sync
 
 
@@ -114,7 +114,7 @@ def test_stream_submit_bitexact_under_concurrent_submits(served):
 
 def test_stream_predict_path_unchanged(served):
     """The synchronous predict() entry point stays bit-identical across
-    engines (it never enters the pipeline)."""
+    servers (it never enters the pipeline)."""
     m, stream, sync = served
     b = SyntheticCTR(m.cfg, 8, seed=9).batch(0)
     np.testing.assert_array_equal(stream.predict(b["dense"], b["cat"]),
@@ -122,24 +122,22 @@ def test_stream_predict_path_unchanged(served):
 
 
 def test_stage_sync_engine_bitexact(served):
-    """The no-overlap benchmark reference engine serves the same bits."""
+    """The stream engine serves the same bits as the no-overlap
+    reference, which blocks every device stage."""
     m, stream, sync = served
-    ss = InferenceServer(m.model, m.dense_params(), stream.hps,
-                         wide_hps=stream.wide_hps, max_batch=8,
-                         engine="stage_sync")
     b = SyntheticCTR(m.cfg, 8, seed=11).batch(3)
-    want = sync.predict(b["dense"], b["cat"])
-    h = ss.submit(b["dense"], b["cat"])
-    ss.start()
+    want = predict_stage_sync(sync, b["dense"], b["cat"])
+    h = stream.submit(b["dense"], b["cat"])
+    stream.start()
     try:
         np.testing.assert_array_equal(h.get(timeout=120), want)
     finally:
-        ss.stop()
+        stream.stop()
 
 
 def _engines_at(m, stream, sync, rows, seed):
     """One request of ``rows`` rows through the stream engine, the
-    ``predict()`` path and the ``stage_sync`` engine: the three answers
+    ``predict()`` path and the no-overlap reference: the three answers
     must be bit-identical, and equal to the dense net applied to the
     unpadded lookups to float tolerance."""
     b = SyntheticCTR(m.cfg, rows, seed=seed).batch(0)
@@ -149,15 +147,11 @@ def _engines_at(m, stream, sync, rows, seed):
         got = h.get(timeout=120)
     finally:
         stream.stop()
-    ss = InferenceServer(m.model, m.dense_params(), stream.hps,
-                         wide_hps=stream.wide_hps,
-                         extra_hps=stream.extra_hps, hotness=stream.hotness,
-                         max_batch=8, engine="stage_sync")
     assert isinstance(got, np.ndarray) and got.shape == (rows,)
     want = sync.predict(b["dense"], b["cat"])
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(
-        ss._predict_stage_sync(b["dense"], b["cat"]), want)
+        predict_stage_sync(stream, b["dense"], b["cat"]), want)
     emb, wide, extras = stream._lookups(
         b["cat"], lambda hps, c, hot: hps.lookup(c, hot))
     assert emb.shape[0] == rows                 # unpadded blocks
@@ -194,8 +188,7 @@ def test_engines_agree_at_uneven_row_counts_n_group(tmp_path):
     assert stream.extra_hps
     sync = InferenceServer(m.model, m.dense_params(), stream.hps,
                            extra_hps=stream.extra_hps,
-                           hotness=stream.hotness, max_batch=8,
-                           engine="sync")
+                           hotness=stream.hotness, max_batch=8)
     for rows in (3, 6):
         _engines_at(m, stream, sync, rows, seed=80 + rows)
 
@@ -239,15 +232,13 @@ def test_stream_dense_stage_error_reaches_own_handle(served):
         got, sync.predict(good["dense"], good["cat"]))
 
 
-@pytest.mark.parametrize("engine", ["stream", "sync"])
-def test_uncoalesceable_requests_error_all_handles(served, engine):
+def test_uncoalesceable_requests_error_all_handles(served):
     """Requests whose widths cannot concatenate into one group must
-    error BOTH handles and leave the serve loop alive — on every
-    engine (the coalescer itself owns that delivery)."""
+    error BOTH handles and leave the serve loop alive (the coalescer
+    itself owns that delivery)."""
     m, stream, sync = served
     srv = InferenceServer(m.model, m.dense_params(), stream.hps,
-                          wide_hps=stream.wide_hps, max_batch=64,
-                          engine=engine)
+                          wide_hps=stream.wide_hps, max_batch=64)
     T = len(m.cfg.tables)
     h1 = srv.submit(np.zeros((4, 13), np.float32),
                     np.zeros((4, T, 1), np.int32))
@@ -263,7 +254,3 @@ def test_uncoalesceable_requests_error_all_handles(served, engine):
         srv.stop()
     np.testing.assert_array_equal(got, sync.predict(b["dense"], b["cat"]))
 
-
-def test_engine_validated():
-    with pytest.raises(ValueError, match="engine"):
-        InferenceServer(object(), {}, None, engine="warp")

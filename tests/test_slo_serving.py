@@ -91,8 +91,7 @@ def test_deadline_target_edges():
 
 def test_full_queue_sheds_exactly_the_overflow():
     depth, extra = 5, 3
-    s = InferenceServer(_NoModel(), {}, None, engine="sync",
-                        queue_depth=depth)
+    s = InferenceServer(_NoModel(), {}, None, queue_depth=depth)
     admitted = [s.submit(*_req()) for _ in range(depth)]
     rejected = [s.submit(*_req()) for _ in range(extra)]
     # the overflow handles resolve IMMEDIATELY with the typed rejection
@@ -108,8 +107,7 @@ def test_full_queue_sheds_exactly_the_overflow():
 
 
 def test_submit_after_close_is_typed_rejection():
-    s = InferenceServer(_NoModel(), {}, None, engine="sync",
-                        queue_depth=4)
+    s = InferenceServer(_NoModel(), {}, None, queue_depth=4)
     pending = s.submit(*_req())
     s.close()
     # close() drained the queued handle with the rejection...
@@ -124,7 +122,7 @@ def test_submit_after_close_is_typed_rejection():
 
 
 def test_set_admission_requires_stopped_server():
-    s = InferenceServer(_NoModel(), {}, None, engine="sync")
+    s = InferenceServer(_NoModel(), {}, None)
     s.start()
     try:
         with pytest.raises(RuntimeError, match="stopped"):
@@ -136,7 +134,7 @@ def test_set_admission_requires_stopped_server():
 
 
 def test_set_admission_shrink_sheds_overflow():
-    s = InferenceServer(_NoModel(), {}, None, engine="sync")
+    s = InferenceServer(_NoModel(), {}, None)
     handles = [s.submit(*_req()) for _ in range(5)]
     s.set_admission(queue_depth=2)
     resolved = [h for h in handles
